@@ -1,9 +1,9 @@
 """Benchmark utilities: wall-clock timing + CSV rows.
 
-Methodology note (EXPERIMENTS.md §Deviation): this container exposes ONE
-physical CPU core, so the paper's speedup-vs-threads axis is reproduced
-structurally (work decomposition + bit-equality under shard counts),
-while WCT comparisons across algorithms / N / α reproduce directly.
+Every record names the device it ran on (platform, ``device_kind``,
+count): a row timed on a CPU host is not a device measurement.  Pallas
+kernels run in interpret mode only where the platform is the CPU
+(``interpret()``), so on a TPU the same rows time the compiled kernels.
 """
 from __future__ import annotations
 
@@ -16,6 +16,11 @@ import jax
 import numpy as np
 
 ROWS: list[tuple[str, float, str]] = []
+
+
+def interpret() -> bool:
+    """Pallas interpret mode: on where the platform is the CPU only."""
+    return jax.devices()[0].platform == "cpu"
 
 
 def plan_for(S, U, algo: str, **spec_kw):
@@ -52,10 +57,13 @@ def emit_header():
 
 def bench_record() -> dict:
     """The accumulated ROWS as a BENCH_*.json-shaped trajectory record."""
+    dev = jax.devices()[0]
     return {
         "meta": {
             "jax": jax.__version__,
             "devices": len(jax.devices()),
+            "device_platform": dev.platform,
+            "device_kind": dev.device_kind,
             "platform": platform.platform(),
         },
         "rows": {name: {"us": us, "derived": derived}

@@ -18,14 +18,10 @@ Serving-layer rows (``repro.serve`` driven through its churn harness):
                                 multi-tenant churn (smoke scale, gated)
   serve/churn_rebuild_p50     — double-buffered rebuild+publish median
                                 (smoke scale, gated)
-  serve/compile_cold|warm     — first-compile vs persistent-cache
-                                warm-start (gate:false — compile-bound)
   serve/churn_n1e6_*          — full-scale trajectory: 1e6 regions,
                                 1e4 moves/tick (full mode only)
 """
 from __future__ import annotations
-
-import time
 
 import numpy as np
 
@@ -68,38 +64,8 @@ def _serve_rows(prefix: str, stats: dict, extra: str = "") -> None:
     row(f"{prefix}_rebuild_p99", stats["rebuild_p99_s"], "")
 
 
-def _compile_cache_rows() -> None:
-    """First-compile vs warm-start through the persistent compilation
-    cache: two fresh ``MatchPlan`` instances at shapes nothing else in
-    this process compiles — the first XLA compile misses the disk cache
-    and writes it, the second should be served from it.  Compile-bound,
-    so both rows are trajectory-only (gate:false in the baseline)."""
-    import tempfile
-
-    from repro.core.engine import MatchPlan
-    from repro.serve import compile_cache
-
-    cache_dir = tempfile.mkdtemp(prefix="repro-jaxcache-")
-    compile_cache.enable(cache_dir)
-    S, U = paper_workload(seed=13, n_total=2994, alpha=5.0)
-    spec = MatchSpec(algo="itm", capacity="fixed", max_pairs=64)
-
-    def first_call_s() -> float:
-        plan = MatchPlan(spec, S.n, U.n, S.d)
-        t0 = time.perf_counter()
-        plan.count(S, U)
-        return time.perf_counter() - t0
-
-    cold = first_call_s()
-    warm = first_call_s()
-    row("serve/compile_cold", cold, "persistent-cache miss (writes it)")
-    row("serve/compile_warm", warm,
-        f"cache hit;speedup={cold / max(warm, 1e-9):.1f}x")
-
-
 def run_smoke() -> None:
-    """Smoke-scale serving churn: the CI-gated p99/rebuild rows plus the
-    (ungated) compile-cache comparison."""
+    """Smoke-scale serving churn: the CI-gated p99/rebuild rows."""
     from repro.serve.harness import run_churn
 
     stats = run_churn(tenants=2, n_total=1024, ticks=4, warmup=2,
@@ -108,7 +74,6 @@ def run_smoke() -> None:
     assert stats["parity_checks"] > 0, "serving oracle never exercised"
     _serve_rows("serve/churn", stats,
                 extra="tenants=2;n=1024;moves=32/tick")
-    _compile_cache_rows()
 
 
 def run_serving_full() -> None:

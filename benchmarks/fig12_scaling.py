@@ -93,11 +93,10 @@ def run():
 _SMOKE_MARK = "FIG12C_SMOKE="
 
 
-def _smoke_c(n: int = 100_000) -> list[tuple[str, float, str]]:
-    """Time the distributed pair emit at the P = 1 and P = 8 endpoints.
+def _smoke_c(n: int = 100_000, ps=(1, 8)) -> list[tuple[str, float, str]]:
+    """Time the distributed pair emit at the mesh sizes ``ps``.
 
-    Needs >= 8 devices (``run_smoke`` forces them in a subprocess when
-    the parent mesh is smaller).  Parity-checks the emitted K against
+    Needs ``max(ps)`` devices.  Parity-checks the emitted K against
     the local engine before timing, so a wrong-but-fast emit can never
     post a row.
     """
@@ -105,7 +104,7 @@ def _smoke_c(n: int = 100_000) -> list[tuple[str, float, str]]:
     k_ref = plan_for(S, U, "sbm", capacity="exact").count(S, U)
     devs = jax.devices()
     out = []
-    for p in (1, 8):
+    for p in ps:
         mesh = Mesh(np.array(devs[:p]), ("shards",))
         plan = plan_for(S, U, "sbm", backend="distributed", mesh=mesh,
                         capacity="exact")
@@ -117,16 +116,20 @@ def _smoke_c(n: int = 100_000) -> list[tuple[str, float, str]]:
 
 
 def run_smoke() -> None:
-    """CI rows for the §c strong-scaling endpoints.
+    """CI rows for the §c strong-scaling endpoints (P = 1 vs P = 8).
 
-    The smoke runner executes on however many devices the host exposes
-    (one, on the CI runners), so the 8-shard measurement runs in a
-    subprocess with ``--xla_force_host_platform_device_count=8`` and
-    ships its rows back over stdout as a marked JSON line; they are
-    re-emitted here so the regression gate sees them like any other row.
+    On an accelerator the endpoints run in this process on the devices
+    that exist (P = 1 and all of them, up to 8): the parent holds the
+    chips, so a child could not reach them.  A CPU host exposes one
+    device, so there the 8-shard measurement runs in a subprocess with
+    ``--xla_force_host_platform_device_count=8`` and ships its rows
+    back over stdout as a marked JSON line; they are re-emitted here so
+    the regression gate sees them like any other row.
     """
-    if len(jax.devices()) >= 8:
-        for name, t, derived in _smoke_c():
+    devs = jax.devices()
+    if devs[0].platform != "cpu" or len(devs) >= 8:
+        for name, t, derived in _smoke_c(ps=sorted({1, min(len(devs),
+                                                            8)})):
             row(name, t, derived)
         return
     env = dict(os.environ,

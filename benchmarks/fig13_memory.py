@@ -18,7 +18,7 @@ from __future__ import annotations
 from repro.core import MatchSpec, build_plan, paper_workload
 from repro.core.grid import _capacities, _cell_spans  # noqa: F401
 
-from .common import row
+from .common import interpret, row
 
 
 def _bytes_regions(n):
@@ -27,7 +27,8 @@ def _bytes_regions(n):
 
 def run():
     # the accounted configurations ARE engine specs (paper's knobs)
-    spec_bfm = MatchSpec(algo="bfm", backend="pallas", interpret=True)
+    spec_bfm = MatchSpec(algo="bfm", backend="pallas",
+                         interpret=interpret())
     spec_gbm = MatchSpec(algo="gbm")
     for n in (10_000, 100_000, 1_000_000):
         S, U = paper_workload(seed=3, n_total=n, alpha=100.0)

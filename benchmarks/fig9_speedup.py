@@ -20,7 +20,7 @@ from repro.core import paper_workload
 from repro.core.sbm import sbm_count_chunked, sbm_count_sweep
 from repro.kernels.ops import sbm_count_pallas
 
-from .common import bench, plan_for, row
+from .common import bench, interpret, plan_for, row
 
 N_MAIN = 1_000_000
 N_BFM = 20_000
@@ -48,11 +48,11 @@ def run():
         counts[algo] = plan.count(S, U)
         row(name, t, f"K={counts[algo]}")
 
-    t = bench(sbm_count_pallas, S, U, block=4096, interpret=True)
+    t = bench(sbm_count_pallas, S, U, block=4096, interpret=interpret())
     counts["sbm_pallas"] = sbm_count_pallas(S, U, block=4096,
-                                            interpret=True)
-    row("fig9/sbm_pallas_interpret_wct_1e6", t,
-        f"K={counts['sbm_pallas']}")
+                                            interpret=interpret())
+    row("fig9/sbm_pallas_wct_1e6", t,
+        f"K={counts['sbm_pallas']};interpret={int(interpret())}")
 
     assert len(set(counts.values())) == 1, counts
     k_ref = sbm_count_sweep(S, U)

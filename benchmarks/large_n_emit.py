@@ -5,15 +5,15 @@ drives the two-pass pair enumeration through every emit route the
 byte-budget policy allows at each size (``kernels.ops.choose_emit_route``:
 resident tables → streamed tables → CSR compressed emit → XLA pass 2),
 asserts the routes are bit-identical on decoded pairs, and times them.
-On this CPU host the Pallas routes run in interpret mode, so their
+On a CPU host the Pallas routes run in interpret mode, so their
 absolute timings are trajectory-only signal; the XLA rows and the
-cross-route parity asserts are the load-bearing part, and on a real TPU
-the same module times the compiled kernels.
+cross-route parity asserts are the load-bearing part, and on a TPU the
+same module times the compiled kernels.
 
-The CSR rows are the 1e7-regime story: past n+m ≈ 2e6 the streamed
-tables no longer fit the VMEM budget, and the csr route's footprint is
-constant in n+m (one table window + two scratch rows), so the sweep's
-top sizes (5e6, 1e7) run csr + xla only.  ``emit_csr_decode_n{N}`` rows
+The CSR rows are the 1e7-regime story: past n+m ≈ 4.2e6 the resident
+permutations no longer fit the VMEM budget, and the csr route's
+footprint is constant in n+m (two output lines and a piece buffer), so
+the sweep's top sizes (5e6, 1e7) run csr + xla only.  ``emit_csr_decode_n{N}`` rows
 time the lazy ``CSRPairs`` view's window decode separately from pass 1.
 
 With pass 2 constant-VMEM under the csr route, pass 1's global XLA
@@ -33,10 +33,9 @@ Rows:
   derived: exact K, the route the policy would pick, truncation flag
 
 ``run_smoke()`` is the CI subset: one size per side of the resident
-threshold (n+m = 1e5 and 6e5) plus 2.2e6 — past the streaming route's
-~2.06e6 byte-budget bound, so CI proves the csr route, not a fallback,
-is what runs in the regime the dense tables cannot reach — plus one
-gated flat-vs-hybrid pass-1 pair at 6e5.
+threshold (n+m = 1e5 and 6e5) plus 2.2e6, each running every route
+the policy allows there, plus one gated flat-vs-hybrid pass-1 pair at
+6e5.
 """
 from __future__ import annotations
 
@@ -46,7 +45,7 @@ import numpy as np
 from repro.core import MatchSpec, build_plan, grid, paper_workload
 from repro.kernels import ops
 
-from .common import bench, row
+from .common import bench, interpret, row
 
 ALPHA = 0.5
 CAP = 8192          # fixed capacity: bounds the interpret-mode grid
@@ -76,7 +75,7 @@ def _sweep(sizes, iters: int = 2) -> None:
         for route in _routes_for(S.n, U.n):
             spec = MatchSpec(algo="sbm", backend="pallas",
                              capacity="fixed", max_pairs=CAP,
-                             emit_route=route, interpret=True)
+                             emit_route=route, interpret=interpret())
             plan = build_plan(spec, S.n, U.n, S.d)
             pairs, k = plan.pairs(S, U)
             if route != "xla":

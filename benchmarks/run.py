@@ -5,7 +5,7 @@ prints ``name,us_per_call,derived`` CSV (fig13 rows carry bytes — see
 the unit tag in `derived`).
 
 ``--smoke`` is the CI mode: compile a MatchPlan and run one tiny sweep
-per backend available on CPU (``xla``, interpret-mode ``pallas``, and
+per backend (``xla``, ``pallas`` — interpret mode on a CPU host — and
 ``distributed`` over the local devices), assert cross-backend parity,
 time the plan-reuse pattern, and measure the fig12c dist_pairs
 strong-scaling endpoints (P = 1 vs P = 8, in an 8-device subprocess)
@@ -23,7 +23,7 @@ import sys
 import time
 
 from .common import (bench, bench_record, check_regression, emit_header,
-                     row, update_baseline, write_bench)
+                     interpret, row, update_baseline, write_bench)
 
 MODULES = [
     "benchmarks.fig9_speedup",
@@ -51,7 +51,7 @@ def smoke() -> None:
         algos = SMOKE_ALGOS if backend != "distributed" else ("sbm",)
         for algo in algos:
             spec = MatchSpec(algo=algo, backend=backend, capacity="grow",
-                             interpret=(backend == "pallas"))
+                             interpret=interpret())
             plan = build_plan(spec, S.n, U.n, S.d)
             k = plan.count(S, U)
             if want is None:
@@ -92,6 +92,9 @@ def main() -> None:
                          "this run's rows (1.5x headroom; preserves "
                          "gate:false markers and the meta note)")
     args = ap.parse_args()
+    from repro.serve import compile_cache
+
+    compile_cache.enable()
     emit_header()
     t0 = time.time()
     if args.smoke:

@@ -2,6 +2,7 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import jax
 import numpy as np
 
 from repro.core import (DDMService, MatchSpec, build_plan, make_regions,
@@ -32,11 +33,13 @@ print(f"\n== paper workload N=1e4 alpha=1: K = {k} "
       f"(E[K] ~ alpha*N/2 = {1.0 * 10_000 / 2:.0f}) ==")
 
 # backend is a config value: the same spec on the Pallas kernels
-# (interpret=True runs the kernel bodies on CPU; drop it on a real TPU)
-pplan = build_plan(MatchSpec(algo="sbm", backend="pallas", interpret=True),
+# (compiled by Mosaic on a TPU; a CPU host runs the kernel bodies in
+# interpret mode)
+on_cpu = jax.devices()[0].platform == "cpu"
+pplan = build_plan(MatchSpec(algo="sbm", backend="pallas", interpret=on_cpu),
                    S1.n, U1.n, S1.d)
 assert pplan.count(S1, U1) == k
-print("   pallas backend agrees (interpret mode)")
+print(f"   pallas backend agrees ({'interpret mode' if on_cpu else 'Mosaic'})")
 
 # --- 3. dynamic DDM (paper §3): move a region, get pair deltas -------------
 svc = DDMService(S1, U1)          # rides the same engine (ITM plan, grow)

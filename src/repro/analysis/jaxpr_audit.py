@@ -54,7 +54,7 @@ _SUBJAXPR_SKIP_F64 = frozenset()   # (reserved: passes that allow f64)
 
 def _subjaxprs_of(params):
     """Sub-jaxprs referenced from an eqn's params (pjit/scan/cond…)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         if isinstance(v, Jaxpr):
             yield v

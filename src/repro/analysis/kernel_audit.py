@@ -27,8 +27,8 @@ regime in milliseconds:
 ``K_ROUTE_DRIFT``
     ``kernels.ops.emit_route_bytes`` (the byte model the route policy
     decides on) re-derived from the captured BlockSpecs/scratch of the
-    *real* emit kernels; the model must bracket the derived bytes to
-    within lane-padding slack for both regimes.
+    *real* emit kernels; the model must equal the derived bytes for
+    every route.
 """
 from __future__ import annotations
 
@@ -39,10 +39,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.emit import VMEM_LIMIT_BYTES
 from .capture import KernelCapture, trace_kernel
 from .report import Report
 
-VMEM_BUDGET = 16 << 20          # v5e-class core VMEM
+# the scoped VMEM limit the emit kernels request from Mosaic
+VMEM_BUDGET = VMEM_LIMIT_BYTES
 GRID_SAMPLE_CAP = 4096          # full enumeration below this many steps
 
 
@@ -217,11 +219,11 @@ def _i32(*shape):
 def derived_table_bytes(cap: KernelCapture) -> int:
     """Route-relevant VMEM bytes from the captured emit-kernel specs.
 
-    Counts what the route *policy* models: VMEM-resident input tables
+    Counts what the route *policy* models: VMEM-resident input blocks
     plus VMEM scratch.  Output blocks and the scalar-prefetch operand
-    are excluded (both regimes pay the same output block, and the
-    policy models table residency only); ANY-space operands stream from
-    HBM and charge their window via the scratch term.
+    are excluded (every route pays the same output block); ANY-space
+    operands stay in HBM and charge only the VMEM scratch they are
+    copied into; SMEM windows do not charge VMEM.
     """
     nsp = cap.num_scalar_prefetch
     total = 0
@@ -238,10 +240,8 @@ def audit_emit_route_parity(report: Report, *, n: int = 4000,
     """Assert ``emit_route_bytes`` matches the real kernels' specs.
 
     All three emit kernels are traced abstractly at ``(n, m,
-    max_pairs)``; the policy's modeled bytes must bracket the
-    spec-derived bytes to within lane-padding slack (each table is
-    padded up to the next 128 lanes, int32; the csr route's footprint
-    is all scratch, so its model must match exactly).  Drift in either
+    max_pairs)``; the policy's modeled bytes must equal the VMEM bytes
+    their captured BlockSpecs and scratch imply.  Drift in either
     direction — a kernel change not reflected in the model, or a model
     change not reflected in the kernels — is ``K_ROUTE_DRIFT``.
     """
@@ -251,19 +251,25 @@ def audit_emit_route_parity(report: Report, *, n: int = 4000,
     block = emit_kernel.DEF_BLOCK if block is None else block
     model = ops.emit_route_bytes(n, m, block=block)
     e = n + m
-    lane = 128 * np.dtype(np.int32).itemsize
-    tables = dict(
-        offs=_i32(e + 1), counts=_i32(e), starts=_i32(e),
-        perm_s=_i32(n), perm_u=_i32(m))
-
-    for route, fn in (("resident", emit_kernel.twopass_emit),
-                      ("streaming", emit_kernel.twopass_emit_streaming)):
+    tables = (_i32(e + 1), _i32(e), _i32(e), _i32(n), _i32(m))
+    win = emit_kernel.stream_window(block)
+    csr_args = (_i32(8, emit_kernel.table_len(e, win)),
+                _i32(1, emit_kernel.perm_len(n)),
+                _i32(1, emit_kernel.perm_len(m)), _i32())
+    routes = (
+        ("resident", functools.partial(
+            emit_kernel.twopass_emit, n=n, m=m, max_pairs=max_pairs,
+            block=block), tables),
+        ("streaming", functools.partial(
+            emit_kernel.twopass_emit_streaming, n=n, m=m,
+            max_pairs=max_pairs, block=block), tables),
+        ("csr", functools.partial(
+            emit_kernel.csr_decode_window, n=n, m=m, nslots=max_pairs,
+            block=block), csr_args),
+    )
+    for route, wrapped, args in routes:
         target = f"emit_route_parity:{route}"
-        wrapped = functools.partial(fn, n=n, m=m, max_pairs=max_pairs,
-                                    block=block)
-        caps = trace_kernel(wrapped, tables["offs"], tables["counts"],
-                            tables["starts"], tables["perm_s"],
-                            tables["perm_u"])
+        caps = trace_kernel(wrapped, *args)
         if len(caps) != 1:
             report.add(
                 "kernel", "K_ROUTE_DRIFT", target,
@@ -271,46 +277,12 @@ def audit_emit_route_parity(report: Report, *, n: int = 4000,
                 f"{route} emit kernel, captured {len(caps)}")
             continue
         derived = derived_table_bytes(caps[0])
-        modeled = model[route]
-        # slack: one lane-round-up per VMEM-charged table
-        n_tables = 5 if route == "resident" else 2
-        slack = n_tables * lane
-        if not modeled <= derived <= modeled + slack:
+        if derived != model[route]:
             report.add(
                 "kernel", "K_ROUTE_DRIFT", target,
-                f"emit_route_bytes models {modeled} bytes for the "
+                f"emit_route_bytes models {model[route]} bytes for the "
                 f"{route} route but the captured BlockSpecs/scratch "
-                f"imply {derived} (allowed [{modeled}, "
-                f"{modeled + slack}]) at (n={n}, m={m}, "
+                f"imply {derived} at (n={n}, m={m}, "
                 f"max_pairs={max_pairs}, block={block}) — the policy "
                 "and the kernels have drifted apart")
         report.note_audit("kernel", target)
-
-    # csr decode: different signature (packed table + padded perms +
-    # a dynamic window start) and an all-scratch footprint — the model
-    # must match the captured scratch exactly, no table slack.
-    target = "emit_route_parity:csr"
-    bl = emit_kernel.lane_pad(block)
-    win = emit_kernel.stream_window(bl)
-    e_pad = e + max((-e) % 128, win - e)
-    wrapped = functools.partial(emit_kernel.csr_decode_window, n=n, m=m,
-                                nslots=max_pairs, block=block)
-    caps = trace_kernel(wrapped, _i32(8, e_pad),
-                        _i32(1, emit_kernel.lane_pad(n + bl)),
-                        _i32(1, emit_kernel.lane_pad(m + bl)), _i32())
-    if len(caps) != 1:
-        report.add(
-            "kernel", "K_ROUTE_DRIFT", target,
-            f"expected exactly one pallas_call while tracing the csr "
-            f"decode kernel, captured {len(caps)}")
-        return
-    derived = derived_table_bytes(caps[0])
-    modeled = model["csr"]
-    if derived != modeled:
-        report.add(
-            "kernel", "K_ROUTE_DRIFT", target,
-            f"emit_route_bytes models {modeled} bytes for the csr "
-            f"route but the captured scratch implies {derived} at "
-            f"(n={n}, m={m}, max_pairs={max_pairs}, block={block}) — "
-            "the policy and the kernels have drifted apart")
-    report.note_audit("kernel", target)

@@ -168,17 +168,15 @@ def _csr_abstract_args(n: int, m: int, *, block: int):
 
     Mirrors the shapes ``ops._csr_tables`` hands to
     ``emit.csr_decode_window``: the packed table floored at the DMA
-    window, the permutations padded for fixed-run over-reads, and the
-    dynamic window-start scalar.
+    window, the padded permutations, and the dynamic window-start
+    scalar.
     """
     from ..kernels import emit as emit_kernel
 
-    bl = emit_kernel.lane_pad(block)
-    win = emit_kernel.stream_window(bl)
-    e = n + m
-    e_pad = e + max((-e) % 128, win - e)
-    return (_i32(8, e_pad), _i32(1, emit_kernel.lane_pad(n + bl)),
-            _i32(1, emit_kernel.lane_pad(m + bl)), _i32())
+    win = emit_kernel.stream_window(block)
+    return (_i32(8, emit_kernel.table_len(n + m, win)),
+            _i32(1, emit_kernel.perm_len(n)),
+            _i32(1, emit_kernel.perm_len(m)), _i32())
 
 
 def audit_ops_hotpaths(report: Report) -> None:

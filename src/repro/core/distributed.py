@@ -66,12 +66,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from . import itm
 from .regions import Regions
-
-# ``jax.shard_map`` is the new-JAX spelling; older versions ship it under
-# jax.experimental with the same signature.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - exercised only on old JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
+from .sbm import saturating_prefix
 
 Array = jax.Array
 AXIS = "shards"
@@ -254,7 +249,7 @@ def _shard_body(v, is_lo, is_upd, valid, splitters, *, cap: int,
 @partial(jax.jit, static_argnames=("nshards", "cap", "blk", "mesh"))
 def _dist_count(v, is_lo, is_upd, valid, splitters, *, nshards: int,
                 cap: int, blk: int, mesh: Mesh):
-    f = _shard_map(
+    f = jax.shard_map(
         partial(_shard_body, cap=cap, nshards=nshards, blk=blk),
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
@@ -340,7 +335,7 @@ def _dist_lo_sort(v, *, splitters, cap: int, nshards: int, mesh: Mesh):
     v = _interleave(v, nshards)         # sorted input must not cluster
     ids = _interleave(ids, nshards)
     valid = _interleave(valid, nshards)
-    f = _shard_map(
+    f = jax.shard_map(
         partial(_sort_side_body, cap=cap, nshards=nshards),
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P()),
@@ -412,9 +407,7 @@ def _pairs_emit_body(emit_lo, emit_hi, u_lo_sorted, s_lo_sorted, perm_s,
     gid, is_b, start, cnt = _chunk_ranges(emit_lo, emit_hi, u_lo_sorted,
                                           s_lo_sorted)
 
-    lim = jnp.int32(cap_dev)
-    sat = lambda a, b: jnp.minimum(a + b, lim)            # noqa: E731
-    incl = jax.lax.associative_scan(sat, jnp.minimum(cnt, lim))
+    incl = saturating_prefix(cnt, cap_dev)
     total = incl[-1]
     loffs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
 
@@ -471,7 +464,7 @@ def _dist_pairs_pass1(S_lo, S_hi, U_lo, U_hi, split_s, split_u, *,
         U_lo[:, 0], splitters=split_u, cap=cap_u, nshards=nshards,
         mesh=mesh)
     emit_lo, emit_hi = _pad_emitters(S_lo, S_hi, U_lo, U_hi, nshards)
-    f = _shard_map(
+    f = jax.shard_map(
         _pairs_count_body,
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(), P()),
@@ -493,7 +486,7 @@ def _dist_pairs_emit(S_lo, S_hi, U_lo, U_hi, u_sorted, s_sorted, perm_s,
     ``(cap, 2)`` view lazily on host.
     """
     emit_lo, emit_hi = _pad_emitters(S_lo, S_hi, U_lo, U_hi, nshards)
-    f = _shard_map(
+    f = jax.shard_map(
         partial(_pairs_emit_body, cap_dev=cap_dev, nshards=nshards),
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(), P(), P(), P(),
@@ -510,16 +503,12 @@ def _dist_pairs_emit(S_lo, S_hi, U_lo, U_hi, u_sorted, s_sorted, perm_s,
 # ---------------------------------------------------------------------------
 
 def _shard_map_norep(f, *, mesh, in_specs, out_specs):
-    """shard_map without the replication checker: the vmapped tree walks
-    are ``while_loop``s, for which check_rep has no rule (outputs here
-    are all row-sharded, so nothing is lost).  Newer JAX drops the
-    kwarg — fall back to the plain call there."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - future-JAX spelling
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    """shard_map without the varying-axes checker: the vmapped tree
+    walks are ``while_loop``s whose carries mix replicated and
+    row-sharded values (outputs here are all row-sharded, so nothing is
+    lost)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _require_float_queries(fn: str, **named):

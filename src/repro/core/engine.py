@@ -381,7 +381,7 @@ class MatchPlan:
         fixed buffer truncates.  Dense-emitting paths return a
         ``DensePairs`` wrapper (``np.asarray``/slicing behave exactly
         like the raw buffer they used to return); the pallas backend's
-        ``csr`` emit route (chosen by the byte policy past n+m ≈ 2e6,
+        ``csr`` emit route (chosen by the byte policy past n+m ≈ 4.2e6,
         or pinned via ``MatchSpec.emit_route``) returns the lazy
         ``kernels.ops.CSRPairs`` subclass — device memory stays
         O(n+m), and any slot window decodes on demand

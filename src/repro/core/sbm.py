@@ -76,9 +76,10 @@ def _sweep_contribs(s_lo, s_hi, u_lo, u_hi) -> Array:
     is_lo, is_upd = _endpoint_stream(s_lo, s_hi, u_lo, u_hi)
     is_hi = 1 - is_lo
     is_sub = 1 - is_upd
-    # active counts AFTER processing endpoint i (inclusive cumsum):
-    upd_active = jnp.cumsum(is_upd * is_lo) - jnp.cumsum(is_upd * is_hi)
-    sub_active = jnp.cumsum(is_sub * is_lo) - jnp.cumsum(is_sub * is_hi)
+    # active counts AFTER processing endpoint i (inclusive cumsum of
+    # the ±1 deltas, both kinds in one scan):
+    upd_active, sub_active = jnp.cumsum(
+        jnp.stack([is_upd, is_sub]) * (is_lo - is_hi)[None, :], axis=1)
     # a hi endpoint's own flags contribute 0 to the opposite kind's counts,
     # so the inclusive cumsum is exactly "UpdSet/SubSet at report time".
     contrib = is_hi * (is_sub * upd_active + is_upd * sub_active)
@@ -193,6 +194,23 @@ def sbm_count_binary(S: Regions, U: Regions) -> int:
 # when the true K exceeds the buffer; the exact K is summed host-side in
 # int64 from the unclipped per-emitter counts.)
 
+def saturating_prefix(counts, lim: int):
+    """Inclusive prefix sum of non-negative int32 ``counts``, saturated
+    at ``lim`` (< 2³¹): ``min(sum(counts[:i+1]), lim)`` exactly, even
+    when the true sum passes 2³¹.
+
+    One wrapping int32 ``cumsum`` (TPU compiles it about 4x faster than
+    a generic ``associative_scan`` at 1e6 elements): each clipped count
+    is below 2³¹, so the first prefix that reaches 2³¹ wraps to a
+    negative value, and every slot from there on saturates.
+    """
+    inc = jnp.cumsum(jnp.minimum(counts, lim))
+    neg = inc < 0
+    first = jnp.where(jnp.any(neg), jnp.argmax(neg), inc.shape[0])
+    sat = jnp.arange(inc.shape[0], dtype=jnp.int32) >= first
+    return jnp.where(sat, jnp.int32(lim), jnp.minimum(inc, lim))
+
+
 def _twopass_phase1(s_lo, s_hi, u_lo, u_hi, max_pairs: int):
     """Pass 1 of count-then-emit: per-emitter counts and slot offsets.
 
@@ -225,9 +243,7 @@ def _twopass_phase1(s_lo, s_hi, u_lo, u_hi, max_pairs: int):
     # and are never selected by the slot lookup.
     starts = jnp.concatenate([aA, bB])
     counts = jnp.concatenate([cnt_a, cnt_b])
-    lim = jnp.int32(max_pairs)
-    incl = jax.lax.associative_scan(
-        lambda a, b: jnp.minimum(a + b, lim), counts)
+    incl = saturating_prefix(counts, max_pairs)
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
     return perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b
 
@@ -378,9 +394,7 @@ def _hsbm_phase1(s_lo, s_hi, u_lo, u_hi, lb, width, *, ncells: int,
     starts = jnp.concatenate([(aA + rows * cap_e_u).reshape(-1),
                               (bB + rows * cap_e_s).reshape(-1)])
     counts = jnp.concatenate([cnt_a.reshape(-1), cnt_b.reshape(-1)])
-    lim = jnp.int32(max_pairs)
-    incl = jax.lax.associative_scan(
-        lambda a, b: jnp.minimum(a + b, lim), jnp.minimum(counts, lim))
+    incl = saturating_prefix(counts, max_pairs)
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
     return (s_emit_ids.reshape(-1), u_emit_ids.reshape(-1),
             starts, counts, offs)

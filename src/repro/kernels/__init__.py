@@ -1,2 +1,3 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU)."""
+"""Pallas TPU kernels: compiled by Mosaic on a TPU; on a CPU host they
+run only in interpret mode (``interpret=True``), as the tests do."""
 from . import bfm, sbm_sweep, ops, ref

@@ -2,80 +2,54 @@
 
 Pass 1 of the exact pair enumeration (``core.sbm._twopass_phase1``)
 produces per-emitter counts and saturated exclusive-scan output offsets
-on the XLA side (sort + searchsorted are already near-roofline there).
-Pass 2 — the slot→(emitter, rank) lookup and the pair write — was an
-XLA ``searchsorted`` + two gathers with three HBM round-trips between
-them; here it is ONE kernel, in two size regimes:
+on the XLA side.  Pass 2 — the slot→(emitter, rank) lookup and the pair
+write — is one Mosaic kernel here, shared by three emit routes that
+differ only in where the tables live:
 
 ``twopass_emit`` (resident)
-    The grid walks the output buffer in (1, B) blocks; offsets, counts,
-    start table and the two sort permutations are read once into VMEM
-    and reused by every program.  Each program binary-searches the
-    offset table for its B slots (lg(n+m) steps, all lanes in
-    lock-step), derives the emitter-local rank, and writes both pair
-    halves.  Runs while all five tables fit the VMEM budget
-    (≈ 4·(n+m) int32 words).
+    The packed emitter table and both sort permutations are copied into
+    VMEM once, at the first grid step, and every output tile reads them
+    from there.  VMEM use ≈ 36·(n+m) bytes.
 
-``twopass_emit_streaming`` (tiled, double-buffered DMA)
-    For the paper's N ≥ 1e6 regime the offset/count/start tables no
-    longer fit VMEM.  The XLA side first *compacts* the emitter tables
-    to the emitters with non-zero counts — compacted offsets are
-    strictly increasing below the saturation limit, so the emitters
-    addressed by one B-slot output tile span at most B + 1 consecutive
-    compacted entries.  It then computes each tile's 128-aligned base
-    index into the compacted tables (a searchsorted over the tile's
-    first slot) and hands those bounds to the kernel as a
-    scalar-prefetch argument.  The kernel keeps the packed
-    (offs/counts/starts/emitter-id) table in HBM (``ANY`` memory
-    space) and double-buffers (B + 256)-wide slices of it through a
-    two-slot VMEM scratch with ``make_async_copy``: while tile ``i``
-    binary-searches its window and writes its pairs, the DMA for tile
-    ``i + 1``'s window is already in flight.  Only the two sort
-    permutations stay VMEM-resident — their gather indices
-    (``start + rank``) are data-dependent and non-local, so no per-tile
-    slice of them exists; they are also the smallest quarter of the
-    table bytes, which is what extends the Pallas route's reach ~4×
-    (to n+m ≈ 2e6 under the default 8 MiB budget) before the XLA
-    fallback takes over.
+``twopass_emit_streaming``
+    The packed table stays in HBM and each tile's window of it streams
+    in by DMA (double-buffered: tile ``i + 1``'s window is in flight
+    while tile ``i`` writes); only the two permutations are copied into
+    VMEM.  VMEM use ≈ 4·(n+m) bytes.
 
-``csr_decode_window`` (CSR route: constant VMEM, nothing resident)
-    Past n+m ≈ 2e6 even the bare permutations outgrow VMEM, and for
-    quadratic-K workloads the dense ``(K, 2)`` output dominates HBM.
-    The CSR route drops both: pass 1's tables *are* a CSR matrix
-    (per-emitter offset + contiguous rank range into a sort
-    permutation), so the route keeps only the packed compacted table
-    plus the two permutations in HBM — O(n+m) words, never O(K) — and
-    decodes any window of slots on demand.  The decode kernel holds a
-    per-tile table window (same packing and bound as the streaming
-    route) and streams the *permutation runs* by DMA: the slots of one
-    output tile select a contiguous range of compacted emitters, and
-    each selected emitter contributes one contiguous ``block``-bounded
-    run of a permutation, so the tile issues at most one fixed-length
-    descriptor per selected emitter (``<= block + 1`` of them).  Runs
-    land in slot order in a scratch line; copies are issued in
-    ascending emitter order so a later run overwrites any earlier
-    run's fixed-length overhang — the slot's owner (the *last* emitter
-    with ``offs[e] <= t``) always writes last.  VMEM use is a constant
-    ``8·win + 2·block`` int32 lanes regardless of n + m, which is what
-    lifts the Pallas emit bound into the 1e7–1e8 region regime.  The
-    lazy ``MatchPlan.pairs()`` view over this kernel lives in
-    ``kernels.ops.CSRPairs``.
+``csr_decode_window`` (CSR route)
+    Nothing is resident: table windows and permutation pieces both
+    stream from HBM, so VMEM use is constant in n+m.  It decodes any
+    window of slots on demand — the lazy ``kernels.ops.CSRPairs`` view.
 
-Slot semantics match the XLA pass 2 bit-for-bit in both regimes: slot
-``t`` belongs to the last emitter ``e`` with ``offs[e] <= t``; its rank
-is ``t − offs[e]``; ranks at or beyond the emitter's count (saturated
-region, or ``t`` past the total) emit the −1 pad.  Class-A emitters
-(``e < n``) own subscription ``e`` and read the update id from the
-lo-sorted U permutation; class-B emitters own update ``e − n`` and read
-the subscription id from the lo-sorted S permutation.  Compaction in
-the streaming path cannot change any emitted pair: a slot's selected
-emitter is the *last* one at its offset value, which always has a
-non-zero count (zero-count emitters share their offset with a
-successor, so they are never last).
+The packed table (``pack_emitter_tables``) keeps only emitters with a
+non-zero count, so compacted offsets are strictly increasing below the
+saturation limit and each emitter ``k`` owns the contiguous slot run
+``[offs[k], offs[k] + counts[k])``.  One output tile of ``B`` slots is
+therefore covered by at most ``B + 1`` consecutive emitters, starting at
+the owner of the tile's first slot.  The XLA side finds that first and
+last owner per tile with one vectorized searchsorted each and passes
+them, with the tile's 128-aligned table window base, as scalar-prefetch
+operands.
 
-Lane-dim tables are padded to 128 multiples with sentinels (offsets:
-INT32_MAX/2, never ≤ any slot id; counts/starts: 0; emitter ids: n+m)
-so padding can never produce a valid slot.
+The kernel walks those emitters in a scalar loop over the table window
+(held in SMEM).  Emitter ``k``'s slots in the tile take a constant own
+half (subscription ``e`` for class A, update ``e − n`` for class B) and
+a contiguous run of the opposite side's sort permutation as the partner
+half.  Mosaic has no cross-vreg vector gather and only tile-aligned
+slices of memory, so a run is moved in pieces of at most 128 lanes:
+load the 256-lane aligned permutation span that holds the piece, rotate
+it into place (``pltpu.roll``) and merge it into the tile's output line
+under a lane mask.  Slots no run covers (past K) keep the −1 pad.
+
+Slot semantics match the XLA pass 2 bit-for-bit: slot ``t`` belongs to
+the last emitter ``e`` with ``offs[e] <= t``; its rank is
+``t − offs[e]``; ranks at or beyond the emitter's count emit the −1
+pad.  Class-A emitters (``e < n``) own subscription ``e`` and read the
+update id from the lo-sorted U permutation; class-B emitters own update
+``e − n`` and read the subscription id from the lo-sorted S
+permutation.  Compaction cannot change any emitted pair: zero-count
+emitters share their offset with a successor, so they are never last.
 """
 from __future__ import annotations
 
@@ -88,10 +62,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 _PAD_OFF = (1 << 30)  # > any slot id; padded offsets are never selected
 DEF_BLOCK = 512
-# streaming window: one output tile of B slots addresses <= B + 1
-# consecutive compacted emitters; +128 covers aligning the window base
-# down to a lane multiple, and the total stays a lane multiple itself.
+# table window: one output tile of B slots addresses <= B + 1 consecutive
+# compacted emitters; +128 covers aligning the window base down to a
+# lane multiple, and the total stays a lane multiple itself.
 STREAM_WIN_EXTRA = 256
+# lanes of one permutation piece; a piece is read from the aligned
+# 2 x 128-lane span that holds it
+PIECE = 128
+SPAN = 2 * PIECE
+# scoped VMEM the emit kernels request (v5e has 128 MiB per core); the
+# route policy's table budget (``kernels.ops``) stays below it
+VMEM_LIMIT_BYTES = 32 << 20
+EMIT_MODES = ("resident", "streaming", "csr")
+# tiles per pallas_call: the scalar-prefetch operand (3 words per tile)
+# and the table windows share the core's 1 MiB of SMEM
+MAX_TILES = 16384
 
 
 def lane_pad(x: int, mult: int = 128) -> int:
@@ -100,52 +85,50 @@ def lane_pad(x: int, mult: int = 128) -> int:
 
 
 def stream_window(block: int) -> int:
-    """Streaming DMA window length (int32 lanes) for an emit ``block``.
+    """Table window length (lanes) for an emit ``block``.
 
-    The single source of truth for the window size: the streaming
-    kernel's VMEM scratch is ``(2, 8, stream_window(block))`` and the
-    route policy's byte model (``kernels.ops.emit_route_bytes``) charges
-    exactly these lanes — the static auditor asserts the two never
-    drift apart.
+    The single source of truth for the window size: the kernels' SMEM
+    window is ``(2, 8, stream_window(block))`` and the packed table is
+    at least this wide.
     """
     return lane_pad(block) + STREAM_WIN_EXTRA
 
 
+def table_len(e: int, win: int) -> int:
+    """Lane length of the packed table for ``e`` emitters."""
+    return max(lane_pad(e), win)
+
+
+def perm_len(x: int) -> int:
+    """Lane length a permutation of ``x`` ids is padded to: every
+    aligned ``SPAN``-lane read of a piece stays in bounds."""
+    return lane_pad(x) + SPAN
+
+
+def emit_vmem_bytes(n: int, m: int, block: int, mode: str) -> int:
+    """VMEM scratch bytes one emit kernel allocates (int32 words x 4).
+
+    Every mode holds the two (1, block + 128) output lines.  ``resident``
+    adds the packed (8, table_len) table and both padded permutations;
+    ``streaming`` adds the permutations only; ``csr`` adds one
+    ``SPAN``-lane piece buffer.  The route policy
+    (``kernels.ops.emit_route_bytes``) charges exactly this, and the
+    static auditor checks it against the kernels' captured scratch.
+    """
+    bl = lane_pad(block)
+    words = 2 * (bl + PIECE)
+    perms = perm_len(n) + perm_len(m)
+    if mode == "resident":
+        words += 8 * table_len(n + m, stream_window(bl)) + perms
+    elif mode == "streaming":
+        words += perms
+    else:
+        words += SPAN
+    return 4 * words
+
+
 def _empty_pairs():
     return jnp.zeros((0, 2), jnp.int32)
-
-
-def _block_slots(i, block: int):
-    t = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    return t[0, :]
-
-
-def _search_last_le(offs, t, span: int):
-    """Largest k in [0, span) with offs[k] <= t, per lane of ``t``."""
-    lo = jnp.zeros_like(t)
-    hi = jnp.full_like(t, span - 1)
-    for _ in range(max((span - 1).bit_length(), 1)):
-        mid = (lo + hi + 1) >> 1
-        go_right = jnp.take(offs, mid) <= t
-        lo = jnp.where(go_right, mid, lo)
-        hi = jnp.where(go_right, hi, mid - 1)
-    return lo
-
-
-def _pair_halves(e, j, start, cnt, perm_s_ref, perm_u_ref, *, n: int,
-                 m: int):
-    """Both pair halves for emitter ``e`` / rank ``j`` (−1 when invalid).
-
-    ``e`` is the original emitter id (may be the n+m sentinel on padded
-    window entries — those carry ``cnt == 0`` and fall to the pad).
-    """
-    valid = (j >= 0) & (j < cnt)
-    is_a = e < n
-    u_from_a = jnp.take(perm_u_ref[0, :], jnp.clip(start + j, 0, m - 1))
-    s_from_b = jnp.take(perm_s_ref[0, :], jnp.clip(start + j, 0, n - 1))
-    s_idx = jnp.where(valid, jnp.where(is_a, e, s_from_b), -1)
-    u_idx = jnp.where(valid, jnp.where(is_a, u_from_a, e - n), -1)
-    return s_idx, u_idx
 
 
 @functools.partial(jax.jit, static_argnames=("n_a", "n_b"))
@@ -178,37 +161,6 @@ def remap_slot_pairs(pairs, sid, uid, *, n_a: int, n_b: int):
     return jnp.stack([s_idx, u_idx], axis=1)
 
 
-# ---------------------------------------------------------------------------
-# resident kernel — all five tables in VMEM for the whole grid
-# ---------------------------------------------------------------------------
-
-def _emit_kernel(offs_ref, counts_ref, starts_ref, perm_s_ref, perm_u_ref,
-                 s_out_ref, u_out_ref, *, n: int, m: int, block: int):
-    i = pl.program_id(0)
-    E = n + m
-    offs = offs_ref[0, :]
-    t = _block_slots(i, block)
-
-    # binary search: largest e in [0, E] with offs[e] <= t  (== the XLA
-    # searchsorted(offs, t, side="right") - 1; offs[0] == 0 <= t always)
-    e = _search_last_le(offs, t, E + 1)
-    j = t - jnp.take(offs, e)
-    e_c = jnp.minimum(e, E - 1)
-    cnt = jnp.where(e < E, jnp.take(counts_ref[0, :], e_c), 0)
-    start = jnp.take(starts_ref[0, :], e_c)
-    s_idx, u_idx = _pair_halves(e_c, j, start, cnt, perm_s_ref,
-                                perm_u_ref, n=n, m=m)
-    s_out_ref[0, :] = s_idx
-    u_out_ref[0, :] = u_idx
-
-
-def _pad_lanes(x, fill, mult: int = 128):
-    pad = (-x.shape[0]) % mult
-    if pad:
-        x = jnp.pad(x, (0, pad), constant_values=fill)
-    return x.reshape(1, -1)
-
-
 def pack_emitter_tables(offs, counts, starts, *, n: int, m: int,
                         min_len: int):
     """Compact + pack pass 1's emitter tables (XLA side, traceable).
@@ -222,8 +174,8 @@ def pack_emitter_tables(offs, counts, starts, *, n: int, m: int,
     original emitter id; rows 4–7 pad to the 8-sublane int32 tile
     height so HBM window slices stay tile-aligned.  ``min_len`` floors
     E_pad at the widest window a consumer will slice; pad entries
-    carry offset ``_PAD_OFF`` and emitter id n + m, so they can never
-    be selected by any in-range slot.
+    carry offset ``_PAD_OFF``, count 0 and emitter id n + m, so they
+    never write a slot.
     """
     E = n + m
     sel = jnp.nonzero(counts > 0, size=E, fill_value=E)[0].astype(jnp.int32)
@@ -234,7 +186,7 @@ def pack_emitter_tables(offs, counts, starts, *, n: int, m: int,
     c_starts = jnp.where(ok, starts[selc], 0)
     c_eorig = jnp.where(ok, sel, E)
 
-    pad = max((-E) % 128, min_len - E)
+    pad = table_len(E, min_len) - E
     if pad > 0:
         c_offs = jnp.pad(c_offs, (0, pad), constant_values=_PAD_OFF)
         c_counts = jnp.pad(c_counts, (0, pad))
@@ -247,15 +199,224 @@ def pack_emitter_tables(offs, counts, starts, *, n: int, m: int,
     return tab
 
 
-def pad_perm_for_runs(perm, run: int):
-    """Pad a sort permutation for fixed-``run``-length DMA over-reads.
+def pad_perm(perm):
+    """A sort permutation as a (1, perm_len) row, zero-padded."""
+    return jnp.pad(perm, (0, perm_len(perm.shape[0]) - perm.shape[0])
+                   ).reshape(1, -1)
 
-    The CSR decode kernel copies a static ``run`` lanes per selected
-    emitter starting at ``start + rank``; the clamp ``rank <= count``
-    keeps the copy start inside the real permutation, so ``run`` extra
-    lanes past the lane-padded end make every over-read in-bounds.
+
+def tile_meta(c_offs, w0, *, nt: int, block: int, win: int,
+              limit: int | None = None):
+    """Scalar-prefetch operand for ``nt`` tiles of ``block`` slots.
+
+    Layout: ``[w0, base[nt], k_first[nt], k_last[nt]]``.  Tile ``i``
+    covers slots ``[w0 + i·block, w0 + (i+1)·block)``; ``k_first`` /
+    ``k_last`` are the owners of its first and last slot below
+    ``limit`` (the saturation limit, when known), and ``base`` is the
+    128-aligned start of the table window that holds them.  ``k_last``
+    is clipped to that window, which only matters for slots at or past
+    the limit — those are trimmed by every caller.
     """
-    return _pad_lanes(jnp.pad(perm, (0, run)), 0)
+    e_pad = c_offs.shape[0]
+    t0 = w0 + jnp.arange(nt, dtype=jnp.int32) * block
+    t_end = t0 + (block - 1)
+    if limit is not None:
+        t_end = jnp.minimum(t_end, limit - 1)
+    k_first = jnp.maximum(
+        jnp.searchsorted(c_offs, t0, side="right").astype(jnp.int32) - 1, 0)
+    base = jnp.minimum((k_first // 128) * 128, e_pad - win)
+    k_last = jnp.minimum(
+        jnp.searchsorted(c_offs, t_end, side="right").astype(jnp.int32) - 1,
+        base + win - 1)
+    return jnp.concatenate([jnp.reshape(w0, (1,)), base, k_first, k_last])
+
+
+# ---------------------------------------------------------------------------
+# the kernel
+# ---------------------------------------------------------------------------
+
+def _emit_kernel(meta_ref, tab_ref, perm_s_ref, perm_u_ref, s_out_ref,
+                 u_out_ref, win_ref, sem_ref, s_line, u_line, *rest,
+                 n: int, nt: int, block: int, win: int, mode: str):
+    """One output tile of ``block`` slots per grid step.
+
+    ``tab_ref`` / ``perm_*_ref`` are in HBM (``ANY``).  ``resident``
+    copies all three into VMEM scratch (``rest``) at step 0; ``streaming``
+    copies the permutations only; ``csr`` copies nothing and reads
+    permutation spans through the ``rest[0]`` piece buffer.  ``win_ref``
+    is the (2, 8, win) SMEM double buffer of table windows.
+    """
+    i = pl.program_id(0)
+    if mode == "resident":
+        tab_src, perm_s_src, perm_u_src = rest
+    elif mode == "streaming":
+        tab_src = tab_ref
+        perm_s_src, perm_u_src = rest
+    else:
+        tab_src, perm_s_src, perm_u_src = tab_ref, perm_s_ref, perm_u_ref
+        (piece_buf,) = rest
+
+    def window_copy(tile, slot):
+        base = pl.multiple_of(meta_ref[1 + tile], 128)
+        return pltpu.make_async_copy(tab_src.at[:, pl.ds(base, win)],
+                                     win_ref.at[slot], sem_ref.at[slot])
+
+    @pl.when(i == 0)
+    def _():
+        if mode != "csr":
+            copies = [(perm_s_ref, perm_s_src), (perm_u_ref, perm_u_src)]
+            if mode == "resident":
+                copies.append((tab_ref, tab_src))
+            for src, dst in copies:
+                cp = pltpu.make_async_copy(src, dst, sem_ref.at[2])
+                cp.start()
+                cp.wait()
+        window_copy(0, 0).start()
+
+    slot = jax.lax.rem(i, 2)
+
+    @pl.when(i + 1 < nt)
+    def _():
+        window_copy(i + 1, 1 - slot).start()
+
+    window_copy(i, slot).wait()
+
+    t0 = meta_ref[0] + i * block
+    base = meta_ref[1 + i]
+    s_line[...] = jnp.full(s_line.shape, -1, jnp.int32)
+    u_line[...] = jnp.full(u_line.shape, -1, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, SPAN), 1)
+
+    def load_span(src_ref, a):
+        if mode == "csr":
+            cp = pltpu.make_async_copy(src_ref.at[:, pl.ds(a, SPAN)],
+                                       piece_buf, sem_ref.at[2])
+            cp.start()
+            cp.wait()
+            return piece_buf[...]
+        return src_ref[:, pl.ds(a, SPAN)]
+
+    def emitter(k, carry):
+        kk = k - base
+        off = win_ref[slot, 0, kk]
+        cnt = win_ref[slot, 1, kk]
+        start = win_ref[slot, 2, kk]
+        e = win_ref[slot, 3, kk]
+        j0 = jnp.maximum(t0 - off, 0)       # first rank inside the tile
+        p0 = jnp.maximum(off - t0, 0)       # its tile-relative slot
+        length = jnp.minimum(cnt - j0, block - p0)
+
+        def piece(q, c):
+            src = start + j0 + q * PIECE
+            dst = p0 + q * PIECE
+            a = pl.multiple_of((src // PIECE) * PIECE, PIECE)
+            cc = pl.multiple_of((dst // PIECE) * PIECE, PIECE)
+            d = dst - cc
+            mask = (lane >= d) & (lane < d + jnp.minimum(length - q * PIECE,
+                                                         PIECE))
+            # span lane r + x holds perm[src + x]; rotate it to lane d + x
+            shift = jax.lax.rem(d - (src - a) + SPAN, SPAN)
+
+            def write(perm_src, part_line, own_line, own):
+                part = pltpu.roll(load_span(perm_src, a), shift, 1)
+                part_line[:, pl.ds(cc, SPAN)] = jnp.where(
+                    mask, part, part_line[:, pl.ds(cc, SPAN)])
+                own_line[:, pl.ds(cc, SPAN)] = jnp.where(
+                    mask, own, own_line[:, pl.ds(cc, SPAN)])
+
+            @pl.when(e < n)
+            def _():
+                write(perm_u_src, u_line, s_line, e)
+
+            @pl.when(e >= n)
+            def _():
+                write(perm_s_src, s_line, u_line, e - n)
+
+            return c
+
+        jax.lax.fori_loop(0, (length + PIECE - 1) // PIECE, piece, 0)
+        return carry
+
+    jax.lax.fori_loop(meta_ref[1 + nt + i], meta_ref[1 + 2 * nt + i] + 1,
+                      emitter, 0)
+    s_out_ref[...] = s_line[:, pl.ds(0, block)]
+    u_out_ref[...] = u_line[:, pl.ds(0, block)]
+
+
+def emit_call(meta, tab, perm_s_pad, perm_u_pad, *, n: int, nt: int,
+              block: int, mode: str, interpret: bool = False):
+    """The bare ``pallas_call``: ``nt`` tiles of ``block`` slots.
+
+    ``tab`` from ``pack_emitter_tables``, the permutations from
+    ``pad_perm``, ``meta`` from ``tile_meta``.  Returns the s and u
+    halves as two (1, nt·block) int32 rows.
+    """
+    win = stream_window(block)
+    scratch = [pltpu.SMEM((2, 8, win), jnp.int32),
+               pltpu.SemaphoreType.DMA((3,)),
+               pltpu.VMEM((1, block + PIECE), jnp.int32),
+               pltpu.VMEM((1, block + PIECE), jnp.int32)]
+    if mode == "resident":
+        scratch.append(pltpu.VMEM(tab.shape, jnp.int32))
+    if mode == "csr":
+        scratch.append(pltpu.VMEM((1, SPAN), jnp.int32))
+    else:
+        scratch += [pltpu.VMEM(perm_s_pad.shape, jnp.int32),
+                    pltpu.VMEM(perm_u_pad.shape, jnp.int32)]
+    out_spec = pl.BlockSpec((1, block), lambda i, meta: (0, i))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nt,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=(out_spec, out_spec),
+        scratch_shapes=scratch,
+    )
+    row = jax.ShapeDtypeStruct((1, nt * block), jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_emit_kernel, n=n, nt=nt, block=block, win=win,
+                          mode=mode),
+        grid_spec=grid_spec,
+        out_shape=(row, row),
+        # steps run in order: step 0's copies and each step's window
+        # prefetch feed the steps after it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name=f"emit_{mode}",
+    )(meta, tab, perm_s_pad, perm_u_pad)
+
+
+def _emit_slots(tab, perm_s_pad, perm_u_pad, w0, *, n: int, nslots: int,
+                block: int, mode: str, interpret: bool,
+                limit: int | None = None):
+    """Slots ``[w0, w0 + nslots)`` as an (nslots, 2) buffer, in calls
+    of at most ``MAX_TILES`` tiles of ``block`` slots each."""
+    win = stream_window(block)
+    nt = -(-nslots // block)
+    halves = []
+    for c0 in range(0, nt, MAX_TILES):
+        nc = min(MAX_TILES, nt - c0)
+        meta = tile_meta(tab[0], w0 + c0 * block, nt=nc, block=block,
+                         win=win, limit=limit)
+        halves.append(emit_call(meta, tab, perm_s_pad, perm_u_pad, n=n,
+                                nt=nc, block=block, mode=mode,
+                                interpret=interpret))
+    s_out = jnp.concatenate([h[0][0] for h in halves])[:nslots]
+    u_out = jnp.concatenate([h[1][0] for h in halves])[:nslots]
+    return jnp.stack([s_out, u_out], axis=1)
+
+
+def _dense_emit(offs, counts, starts, perm_s, perm_u, *, n: int, m: int,
+                max_pairs: int, block: int, mode: str, interpret: bool):
+    if max_pairs == 0:
+        return _empty_pairs()
+    bl = min(lane_pad(block), max(128, lane_pad(max_pairs)))
+    tab = pack_emitter_tables(offs, counts, starts, n=n, m=m,
+                              min_len=stream_window(bl))
+    return _emit_slots(tab, pad_perm(perm_s), pad_perm(perm_u),
+                       jnp.int32(0), n=n, nslots=max_pairs, block=bl,
+                       mode=mode, interpret=interpret, limit=max_pairs)
 
 
 @functools.partial(jax.jit,
@@ -264,92 +425,19 @@ def pad_perm_for_runs(perm, run: int):
 def twopass_emit(offs, counts, starts, perm_s, perm_u, *, n: int, m: int,
                  max_pairs: int, block: int = DEF_BLOCK,
                  interpret: bool = False):
-    """Pass-2 pair write: (max_pairs, 2) int32, −1 padded.
+    """Pass-2 pair write, tables VMEM-resident: (max_pairs, 2) int32.
 
     ``offs`` is the (n+m+1,) saturated exclusive scan from pass 1,
     ``counts``/``starts`` the (n+m,) per-emitter tables, ``perm_s``/
     ``perm_u`` the lo-sort permutations.  Output slot order is identical
-    to the XLA pass 2 in ``core.sbm._twopass_emit``.  ``max_pairs == 0``
-    short-circuits to an empty (0, 2) buffer (a zero-size grid is not a
-    legal ``pallas_call``), matching the engine's empty-set guarantees.
+    to the XLA pass 2 in ``core.sbm._twopass_emit``, −1 padded.
+    ``max_pairs == 0`` short-circuits to an empty (0, 2) buffer (a
+    zero-size grid is not a legal ``pallas_call``), matching the
+    engine's empty-set guarantees.
     """
-    if max_pairs == 0:
-        return _empty_pairs()
-    bl = min(block, max(128, max_pairs))
-    t_pad = (-max_pairs) % bl
-    total = max_pairs + t_pad
-    grid = (total // bl,)
-    offs_p = _pad_lanes(offs, _PAD_OFF)
-    counts_p = _pad_lanes(counts, 0)
-    starts_p = _pad_lanes(starts, 0)
-    perm_s_p = _pad_lanes(perm_s, 0)
-    perm_u_p = _pad_lanes(perm_u, 0)
-
-    full = lambda arr: pl.BlockSpec(arr.shape, lambda i: (0, 0))
-    s_out, u_out = pl.pallas_call(
-        functools.partial(_emit_kernel, n=n, m=m, block=bl),
-        grid=grid,
-        in_specs=[full(offs_p), full(counts_p), full(starts_p),
-                  full(perm_s_p), full(perm_u_p)],
-        out_specs=(pl.BlockSpec((1, bl), lambda i: (0, i)),
-                   pl.BlockSpec((1, bl), lambda i: (0, i))),
-        out_shape=(jax.ShapeDtypeStruct((1, total), jnp.int32),
-                   jax.ShapeDtypeStruct((1, total), jnp.int32)),
-        interpret=interpret,
-    )(offs_p, counts_p, starts_p, perm_s_p, perm_u_p)
-    return jnp.stack([s_out[0, :max_pairs], u_out[0, :max_pairs]], axis=1)
-
-
-# ---------------------------------------------------------------------------
-# streaming kernel — tables tiled through a double-buffered VMEM window
-# ---------------------------------------------------------------------------
-
-def _emit_stream_kernel(base_ref, tab_ref, perm_s_ref, perm_u_ref,
-                        s_out_ref, u_out_ref, win_ref, sem_ref, *,
-                        n: int, m: int, block: int, win: int):
-    """One output tile per program; emitter tables stream in by DMA.
-
-    ``base_ref`` (scalar prefetch) holds each tile's 128-aligned base
-    index into the packed compacted table ``tab_ref`` (HBM-resident,
-    rows: offsets / counts / starts / original emitter id).  ``win_ref``
-    is the (2, 8, win) double-buffer scratch; while tile ``i`` computes
-    out of one slot, tile ``i+1``'s window copies into the other.
-    """
-    i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    slot = jax.lax.rem(i, 2)
-    nxt = jax.lax.rem(i + 1, 2)
-
-    def tile_copy(tile, s):
-        return pltpu.make_async_copy(
-            tab_ref.at[:, pl.ds(base_ref[tile], win)],
-            win_ref.at[s], sem_ref.at[s])
-
-    @pl.when(i == 0)
-    def _():
-        tile_copy(0, 0).start()
-
-    @pl.when(i + 1 < nt)
-    def _():
-        tile_copy(i + 1, nxt).start()
-
-    tile_copy(i, slot).wait()
-
-    window = win_ref[slot]            # (8, win) int32
-    offs_w = window[0, :]
-    t = _block_slots(i, block)
-    # the window covers every emitter this tile's slots can select
-    # (compacted offsets are strictly increasing below saturation), so
-    # the local search equals the global one wherever a slot is valid.
-    k = _search_last_le(offs_w, t, win)
-    j = t - jnp.take(offs_w, k)
-    cnt = jnp.take(window[1, :], k)
-    start = jnp.take(window[2, :], k)
-    e = jnp.take(window[3, :], k)
-    s_idx, u_idx = _pair_halves(e, j, start, cnt, perm_s_ref,
-                                perm_u_ref, n=n, m=m)
-    s_out_ref[0, :] = s_idx
-    u_out_ref[0, :] = u_idx
+    return _dense_emit(offs, counts, starts, perm_s, perm_u, n=n, m=m,
+                       max_pairs=max_pairs, block=block, mode="resident",
+                       interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -359,146 +447,11 @@ def twopass_emit_streaming(offs, counts, starts, perm_s, perm_u, *,
                            n: int, m: int, max_pairs: int,
                            block: int = DEF_BLOCK,
                            interpret: bool = False):
-    """Streaming pass-2 pair write — bit-identical to ``twopass_emit``.
-
-    XLA-side prep: compact the emitter tables to non-zero counts (so
-    one output tile spans <= block + 1 consecutive entries), pack them
-    into one (8, E_pad) int32 array that stays in HBM, and compute each
-    tile's aligned window base with one vectorized searchsorted.  The
-    kernel then double-buffers (8, block + 256) windows through VMEM.
-    """
-    if max_pairs == 0:
-        return _empty_pairs()
-    E = n + m
-    # lane-multiple tile (the DMA window slice must be 128-aligned)
-    bl = min(lane_pad(block), max(128, lane_pad(max_pairs)))
-    win = stream_window(bl)
-    t_pad = (-max_pairs) % bl
-    total = max_pairs + t_pad
-    nt = total // bl
-
-    tab = pack_emitter_tables(offs, counts, starts, n=n, m=m, min_len=win)
-    e_pad = tab.shape[1]
-    c_offs = tab[0]
-
-    t0 = jnp.arange(nt, dtype=jnp.int32) * bl
-    k0 = jnp.searchsorted(c_offs, t0, side="right").astype(jnp.int32) - 1
-    base = (jnp.maximum(k0, 0) // 128) * 128
-    base = jnp.minimum(base, e_pad - win)
-
-    perm_s_p = _pad_lanes(perm_s, 0)
-    perm_u_p = _pad_lanes(perm_u, 0)
-
-    full = lambda arr: pl.BlockSpec(arr.shape, lambda i, b: (0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                  full(perm_s_p), full(perm_u_p)],
-        out_specs=(pl.BlockSpec((1, bl), lambda i, b: (0, i)),
-                   pl.BlockSpec((1, bl), lambda i, b: (0, i))),
-        scratch_shapes=[pltpu.VMEM((2, 8, win), jnp.int32),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    s_out, u_out = pl.pallas_call(
-        functools.partial(_emit_stream_kernel, n=n, m=m, block=bl,
-                          win=win),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((1, total), jnp.int32),
-                   jax.ShapeDtypeStruct((1, total), jnp.int32)),
-        interpret=interpret,
-    )(base, tab, perm_s_p, perm_u_p)
-    return jnp.stack([s_out[0, :max_pairs], u_out[0, :max_pairs]], axis=1)
-
-
-# ---------------------------------------------------------------------------
-# CSR decode kernel — constant VMEM; permutation runs stream in by DMA
-# ---------------------------------------------------------------------------
-
-def _scalar_at(vec, idx):
-    """vec[idx] as a traced scalar (dynamic index into a loaded vector)."""
-    return jax.lax.dynamic_slice(vec, (idx,), (1,))[0]
-
-
-def _csr_decode_kernel(meta_ref, tab_ref, perm_s_ref, perm_u_ref,
-                       s_out_ref, u_out_ref, tab_win_ref, run_ref,
-                       sem_ref, *, n: int, m: int, block: int, win: int,
-                       run: int):
-    """Decode one tile of pair slots from the CSR form.
-
-    ``meta_ref`` (scalar prefetch): slot 0 is the decode window's first
-    global slot id ``w0`` (dynamic — one compile covers every window
-    offset of a given size), slots 1.. are each tile's 128-aligned base
-    into the packed table.  ``tab_ref`` / ``perm_s_ref`` / ``perm_u_ref``
-    stay in HBM (``ANY``); per tile the kernel copies one (8, win)
-    table window in, binary-searches the owning emitter per lane, then
-    issues one fixed-``run``-length DMA per selected emitter, landing
-    the permutation runs at slot-relative positions in the ``run_ref``
-    scratch line.  Copies go in ascending emitter order: slot ``p``'s
-    owner is the *last* emitter whose run covers ``p``, so its copy is
-    the final write there and any earlier run's overhang is dead.
-    """
-    i = pl.program_id(0)
-    tab_cp = pltpu.make_async_copy(
-        tab_ref.at[:, pl.ds(meta_ref[1 + i], win)],
-        tab_win_ref, sem_ref.at[0])
-    tab_cp.start()
-    tab_cp.wait()
-
-    window = tab_win_ref[...]         # (8, win) int32
-    offs_w = window[0, :]
-    t0 = meta_ref[0] + i * block
-    t = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0, :]
-    k = _search_last_le(offs_w, t, win)
-    j = t - jnp.take(offs_w, k)
-    cnt = jnp.take(window[1, :], k)
-    e = jnp.take(window[3, :], k)
-
-    # every lane's selection lies in [k_lo, k_hi]; the range is all
-    # real emitters (pads sit past every selectable entry), so the
-    # class split below never sees the n+m sentinel.
-    k_lo = jnp.min(k)
-    n_runs = jnp.max(k) - k_lo + 1
-
-    def copy_run(src_ref, src0, p0):
-        cp = pltpu.make_async_copy(
-            src_ref.at[0, pl.ds(src0, run)],
-            run_ref.at[0, pl.ds(p0, run)], sem_ref.at[1])
-        cp.start()
-        cp.wait()
-
-    def body(r, carry):
-        kk = k_lo + r
-        off_r = _scalar_at(offs_w, kk)
-        cnt_r = _scalar_at(window[1, :], kk)
-        start_r = _scalar_at(window[2, :], kk)
-        e_r = _scalar_at(window[3, :], kk)
-        # first rank this tile needs from emitter kk, clamped to its
-        # count: start + j0 <= start + count stays inside the real
-        # permutation (class A: aA + cnt_a = rank_hi <= m, and
-        # symmetrically for class B), so the fixed-length over-read
-        # lands in pad_perm_for_runs's tail padding.
-        j0 = jnp.clip(t0 - off_r, 0, cnt_r)
-        p0 = jnp.maximum(off_r - t0, 0)   # slot-relative landing spot
-        src0 = start_r + j0
-
-        @pl.when(e_r < n)
-        def _():
-            copy_run(perm_u_ref, src0, p0)
-
-        @pl.when(e_r >= n)
-        def _():
-            copy_run(perm_s_ref, src0, p0)
-
-        return carry
-
-    jax.lax.fori_loop(0, n_runs, body, 0)
-
-    v = run_ref[0, pl.ds(0, block)]
-    valid = (j >= 0) & (j < cnt)
-    is_a = e < n
-    s_out_ref[0, :] = jnp.where(valid, jnp.where(is_a, e, v), -1)
-    u_out_ref[0, :] = jnp.where(valid, jnp.where(is_a, v, e - n), -1)
+    """Streaming pass-2 pair write — bit-identical to ``twopass_emit``,
+    with the packed table streamed from HBM per tile."""
+    return _dense_emit(offs, counts, starts, perm_s, perm_u, n=n, m=m,
+                       max_pairs=max_pairs, block=block, mode="streaming",
+                       interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -512,52 +465,23 @@ def csr_decode_window(tab, perm_s_pad, perm_u_pad, w0, *, n: int, m: int,
     ``tab`` is the packed compacted emitter table from
     ``pack_emitter_tables`` (built with ``min_len >=
     stream_window(lane_pad(block))``), ``perm_s_pad`` / ``perm_u_pad``
-    the permutations padded by ``pad_perm_for_runs``.  Returns the
-    (nslots, 2) int32 slots ``[w0, w0 + nslots)`` of the dense pass-2
-    buffer, bit-identical to ``core.sbm._twopass_emit`` on that window
-    (slots at or past the emit capacity decode to the −1 pad — callers
-    must trim to the capacity themselves; see ``kernels.ops.CSRPairs``).
-    ``w0`` is a traced operand: decoding a different window of the same
-    size never retraces.
+    the permutations padded by ``pad_perm``.  Returns the (nslots, 2)
+    int32 slots ``[w0, w0 + nslots)`` of the dense pass-2 buffer,
+    bit-identical to ``core.sbm._twopass_emit`` on every slot below the
+    emit capacity (callers trim at the capacity; see
+    ``kernels.ops.CSRPairs``).  ``w0`` is a traced operand: decoding a
+    different window of the same size never retraces.
     """
     if nslots == 0:
         return _empty_pairs()
     e_pad = tab.shape[1]
     bl = min(lane_pad(block), max(128, lane_pad(nslots)))
     win = stream_window(bl)
-    run = bl
     if e_pad < win:
         raise ValueError(
             f"packed table length {e_pad} is narrower than the decode "
             f"window {win}; pack with min_len >= stream_window("
             f"lane_pad(block)) (block={block})")
-    t_pad = (-nslots) % bl
-    total = nslots + t_pad
-    nt = total // bl
-
-    w0 = jnp.asarray(w0, jnp.int32)
-    t0s = w0 + jnp.arange(nt, dtype=jnp.int32) * bl
-    k0 = jnp.searchsorted(tab[0], t0s, side="right").astype(jnp.int32) - 1
-    base = (jnp.maximum(k0, 0) // 128) * 128
-    base = jnp.clip(base, 0, e_pad - win)
-    meta = jnp.concatenate([jnp.reshape(w0, (1,)), base])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)] * 3,
-        out_specs=(pl.BlockSpec((1, bl), lambda i, mref: (0, i)),
-                   pl.BlockSpec((1, bl), lambda i, mref: (0, i))),
-        scratch_shapes=[pltpu.VMEM((8, win), jnp.int32),
-                        pltpu.VMEM((1, bl + run), jnp.int32),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    s_out, u_out = pl.pallas_call(
-        functools.partial(_csr_decode_kernel, n=n, m=m, block=bl,
-                          win=win, run=run),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((1, total), jnp.int32),
-                   jax.ShapeDtypeStruct((1, total), jnp.int32)),
-        interpret=interpret,
-    )(meta, tab, perm_s_pad, perm_u_pad)
-    return jnp.stack([s_out[0, :nslots], u_out[0, :nslots]], axis=1)
+    return _emit_slots(tab, perm_s_pad, perm_u_pad,
+                       jnp.asarray(w0, jnp.int32), n=n, nslots=nslots,
+                       block=bl, mode="csr", interpret=interpret)

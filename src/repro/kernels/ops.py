@@ -2,10 +2,10 @@
 
 These handle padding to tile multiples (with non-matching sentinel
 regions / zero-contribution sentinel endpoints), call the kernels, and
-trim back — so callers never see tile-size constraints.  ``interpret=True``
-(default off) runs the kernel bodies in Python on CPU; ops are used with
-interpret mode in tests and benchmarks on this host, and compile to
-Mosaic on real TPUs.
+trim back — so callers never see tile-size constraints.  On a TPU the
+kernels compile with Mosaic; ``interpret=True`` (default off) runs the
+kernel bodies as ordinary XLA instead, which is how a CPU host (the
+tests, CI) runs them.
 """
 from __future__ import annotations
 
@@ -109,19 +109,20 @@ def _twopass_tables(s_lo, s_hi, u_lo, u_hi, max_pairs):
     return perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b
 
 
-# Emit-route policy.  The resident emit kernel keeps all five lookup
-# tables VMEM-resident (shared by every grid step); past the byte budget
-# they cannot fit beside the output block on a real TPU core.  The
-# streaming kernel DMAs the offset/count/start tables per tile and only
-# keeps the two sort permutations resident, reaching ~4x further.  The
-# csr route keeps NOTHING resident — tables and permutation runs both
-# stream per tile, so its footprint is constant in n+m and the route's
-# reach is unbounded; it returns a lazy CSRPairs view instead of a
-# dense buffer, so the d>1 verify path (which needs dense candidates)
-# falls through to the bit-identical XLA pass 2 instead.  Tests
+# Emit-route policy.  The resident emit kernel copies the packed table
+# and both sort permutations into VMEM; past the byte budget they cannot
+# fit.  The streaming kernel streams the table from HBM per tile and
+# only keeps the two permutations resident, reaching ~9x further.  The
+# csr route keeps NOTHING resident — table windows and permutation
+# pieces both stream per tile, so its footprint is constant in n+m; it
+# returns a lazy CSRPairs view instead of a dense buffer, so the d>1
+# verify path (which needs dense candidates) falls through to the
+# bit-identical XLA pass 2 instead.  The budget leaves headroom under
+# the scoped VMEM limit the kernels request (emit.VMEM_LIMIT_BYTES) for
+# the pipelined output blocks and Mosaic's own scratch.  Tests
 # monkeypatch the budget to exercise every route at small sizes.
-_EMIT_VMEM_TABLE_BUDGET = 8 << 20
-EMIT_ROUTES = ("auto", "resident", "streaming", "csr", "xla")
+_EMIT_VMEM_TABLE_BUDGET = 16 << 20
+EMIT_ROUTES = ("auto",) + emit_kernel.EMIT_MODES + ("xla",)
 
 # last route taken by twopass_pairs_pallas (None before any call /
 # after an empty-set short-circuit) — lets tests and benchmarks prove
@@ -135,27 +136,16 @@ def last_emit_route() -> str | None:
 
 def emit_route_bytes(n: int, m: int, *, block: int = emit_kernel.DEF_BLOCK
                      ) -> dict:
-    """VMEM byte math behind the route policy (int32 words x 4).
+    """VMEM bytes each emit route allocates for an (n, m) problem.
 
-    ``resident``: offsets (n+m+1) + counts + starts (n+m each) + the two
-    permutations (n + m) all live in VMEM for the whole grid.
-    ``streaming``: only the permutations are resident; the packed
-    emitter table streams through a double-buffered 2 x (8, block+256)
-    window.
-    ``csr``: nothing is resident — one (8, win) table window plus one
-    (1, 2·block) run-landing line per tile, both DMA-fed.  Constant in
-    n + m, so the csr need never exceeds any budget the other kernels
-    fit (the decode kernel's reach is bounded by int32 slot ids, not
-    by VMEM).
+    Exactly the kernels' VMEM scratch (``emit.emit_vmem_bytes``):
+    ``resident`` holds the packed (8, n+m) table and both permutations,
+    ``streaming`` the permutations only, ``csr`` a constant few KiB.
+    The static auditor re-derives these bytes from the captured kernel
+    specs and fails on any drift.
     """
-    e = n + m
-    bl = emit_kernel.lane_pad(block)
-    win = emit_kernel.stream_window(bl)
-    return {
-        "resident": 4 * (3 * (e + 1) + e),
-        "streaming": 4 * e + 2 * 8 * win * 4,
-        "csr": 4 * (8 * win + 2 * bl),
-    }
+    return {mode: emit_kernel.emit_vmem_bytes(n, m, block, mode)
+            for mode in emit_kernel.EMIT_MODES}
 
 
 def choose_emit_route(n: int, m: int, *,
@@ -269,13 +259,11 @@ def _csr_tables(s_lo, s_hi, u_lo, u_hi, max_pairs, block):
     n, m = s_lo.shape[0], u_lo.shape[0]
     perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b = _twopass_phase1(
         s_lo, s_hi, u_lo, u_hi, max_pairs)
-    bl = emit_kernel.lane_pad(block)
     tab = emit_kernel.pack_emitter_tables(
         offs, counts, starts, n=n, m=m,
-        min_len=emit_kernel.stream_window(bl))
-    ps = emit_kernel.pad_perm_for_runs(perm_s, bl)
-    pu = emit_kernel.pad_perm_for_runs(perm_u, bl)
-    return tab, ps, pu, cnt_a, cnt_b
+        min_len=emit_kernel.stream_window(block))
+    return (tab, emit_kernel.pad_perm(perm_s), emit_kernel.pad_perm(perm_u),
+            cnt_a, cnt_b)
 
 
 def twopass_pairs_csr(S: Regions, U: Regions, max_pairs: int, *,
@@ -389,13 +377,11 @@ def _hsbm_csr_tables(s_lo, s_hi, u_lo, u_hi, lb, width, *, ncells, cap_s,
         suf_s=suf_s, cap_u=cap_u, suf_u=suf_u, max_pairs=max_pairs)
     n_a = ncells * (cap_s + suf_s)
     n_b = ncells * (cap_u + suf_u)
-    bl = emit_kernel.lane_pad(block)
     tab = emit_kernel.pack_emitter_tables(
         offs, counts, starts, n=n_a, m=n_b,
-        min_len=emit_kernel.stream_window(bl))
-    ps = emit_kernel.pad_perm_for_runs(sid + n_a, bl)
-    pu = emit_kernel.pad_perm_for_runs(uid + n_b, bl)
-    return tab, ps, pu, sid, uid, counts
+        min_len=emit_kernel.stream_window(block))
+    return (tab, emit_kernel.pad_perm(sid + n_a),
+            emit_kernel.pad_perm(uid + n_b), sid, uid, counts)
 
 
 class HsbmCSRPairs(CSRPairs):

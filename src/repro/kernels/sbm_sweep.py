@@ -7,7 +7,8 @@ the paper's own two-level scan, one level down the memory hierarchy: the
 grid walks the endpoint stream in (1, C) VMEM blocks **sequentially**
 (TPU grid order is sequential, which is what makes a carried scan legal);
 each program computes the local inclusive scans of the update/
-subscription active-deltas — Alg. 7 step ① — adds the carry from all
+subscription active-deltas — Alg. 7 step ①, as a log-step shifted add
+(``pltpu.roll``; Mosaic has no cumsum lowering) — adds the carry from all
 previous blocks — step ② — and emits the per-endpoint report counts of
 the seeded sweep — step ③.  The two carries (active update/sub counts)
 live in SMEM scratch across grid steps.
@@ -25,6 +26,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _lane_scan(x):
+    """Inclusive prefix sum along the lanes of a (1, C) block: lg C
+    rotate-and-add steps, each masked so nothing wraps around."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < x.shape[1]:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0)
+        shift *= 2
+    return x
+
+
 def _sweep_kernel(is_lo_ref, is_upd_ref, out_ref, carry_ref):
     i = pl.program_id(0)
 
@@ -40,8 +52,8 @@ def _sweep_kernel(is_lo_ref, is_upd_ref, out_ref, carry_ref):
 
     d_upd = is_upd * (is_lo - is_hi)
     d_sub = is_sub * (is_lo - is_hi)
-    upd_local = jnp.cumsum(d_upd, axis=1)    # step ① local scan
-    sub_local = jnp.cumsum(d_sub, axis=1)
+    upd_local = _lane_scan(d_upd)            # step ① local scan
+    sub_local = _lane_scan(d_sub)
     upd_active = upd_local + carry_ref[0]    # step ② seeded
     sub_active = sub_local + carry_ref[1]
     out_ref[...] = is_hi * (is_sub * upd_active + is_upd * sub_active)

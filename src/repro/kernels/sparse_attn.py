@@ -43,12 +43,8 @@ def _kernel(qid_ref, starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref, *,
 
     def attend(kv_off, carry):
         acc, m, l = carry
-        # leading index as a 1-slice (not a bare int): older Pallas
-        # interpret-mode discharge only accepts Slice/array indices
-        kblk = pl.load(k_ref, (pl.ds(0, 1), pl.ds(kv_off, bkv),
-                               slice(None)))[0]
-        vblk = pl.load(v_ref, (pl.ds(0, 1), pl.ds(kv_off, bkv),
-                               slice(None)))[0]
+        kblk = k_ref[0, pl.ds(kv_off, bkv), :]
+        vblk = v_ref[0, pl.ds(kv_off, bkv), :]
         s = q @ kblk.astype(jnp.float32).T               # (bq, bkv)
         kv_pos = kv_off + jax.lax.iota(jnp.int32, bkv)
         ok = (kv_pos[None, :] <= q_pos[:, None]) & \

@@ -32,8 +32,7 @@ import numpy as np
 from repro.configs import (ALIASES, ARCHS, SHAPES, get_config,
                            get_smoke_config, shape_applicable)
 from repro.launch import partition as pt
-from repro.launch.mesh import (compat_make_mesh, make_production_mesh,
-                               mesh_context)
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.steps import (abstract_cache, abstract_opt,
                                 abstract_params, input_structs,
                                 make_decode_step, make_prefill_step,
@@ -160,7 +159,7 @@ def _compile_cell(cfg, spec, mesh):
         out_specs = (logits_spec, cspecs)
         args = (pstruct, cstruct, batch_struct)
         donate = (1,)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn,
                          in_shardings=pt.named(mesh, in_specs),
                          out_shardings=pt.named(mesh, out_specs),
@@ -223,7 +222,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     if smoke:  # selftest: tiny mesh, same axis names
         shape_ax = ((2, 2, 4), ("pod", "data", "model")) if multi_pod \
             else ((4, 4), ("data", "model"))
-        mesh = compat_make_mesh(shape_ax[0], shape_ax[1])
+        mesh = make_mesh(shape_ax[0], shape_ax[1])
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_dev = int(np.prod(mesh.devices.shape))

@@ -9,47 +9,21 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    JAX supports them.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) only exist on
-    newer JAX; on older versions every mesh axis is Auto by default, so
-    falling back to a plain ``make_mesh`` is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates kwarg
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` is the new-JAX spelling; older versions use the
-    ``Mesh`` object's own context manager (the ambient *physical* mesh),
-    which the sharding-constraint resolution in ``models.sharding``
-    reads through its matching fallback.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis explicitly Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Whatever this host has — used by tests/examples, not dry-runs."""
     n = len(jax.devices())
     data = n // model_axis
-    return compat_make_mesh((data, model_axis), ("data", "model"))
+    return make_mesh((data, model_axis), ("data", "model"))

@@ -19,28 +19,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_mesh():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is None or not mesh.axis_names:
-            return None
-        return mesh
-    # older JAX: the ambient *physical* mesh installed by `with mesh:`
-    try:  # pragma: no cover - exercised only on old JAX
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or mesh.empty or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
-
-
-def _axis_sizes(mesh) -> dict:
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(mesh.axis_names, sizes))
-    return {a: mesh.shape[a] for a in mesh.axis_names}
 
 
 def _resolve(name, axis_names):
@@ -75,7 +57,7 @@ def constrain(x, *logical):
     mesh = _ambient_mesh()
     if mesh is None:
         return x
-    sizes = _axis_sizes(mesh)
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     dims = []
     for dim_size, name in zip(x.shape, logical):
         ax = _resolve(name, mesh.axis_names)

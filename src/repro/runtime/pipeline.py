@@ -22,17 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# ``jax.shard_map`` is the new-JAX spelling; older versions ship it under
-# jax.experimental with the same signature.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - exercised only on old JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# ``pvary`` marks a carry as axis-varying for new-JAX shard_map's varying
-# -manual-axes type system; older shard_map has no such tracking, where
-# the identity is the correct no-op.
-_pvary = getattr(jax.lax, "pvary", lambda x, axis: x)
-
 AXIS = "stage"
 
 
@@ -71,8 +60,8 @@ def pipeline_forward(stacked_params, x, layer_apply, *, mesh: Mesh,
 
         ticks = n_microbatches + n_stages - 1
         # carries must be stage-varying for the shard_map type system
-        buf = _pvary(jnp.zeros_like(xs[0]), AXIS)
-        outs = _pvary(jnp.zeros_like(xs), AXIS)
+        buf = jax.lax.pcast(jnp.zeros_like(xs[0]), AXIS, to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), AXIS, to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -100,7 +89,7 @@ def pipeline_forward(stacked_params, x, layer_apply, *, mesh: Mesh,
         outs = jax.lax.psum(outs, AXIS)
         return outs[None]
 
-    f = _shard_map(
+    f = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(P(AXIS), P()),
         out_specs=P(AXIS),

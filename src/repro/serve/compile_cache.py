@@ -1,14 +1,12 @@
-"""Persistent JAX compilation cache for the serving layer.
+"""Persistent JAX compilation cache.
 
-First-compile latency is the serving layer's cold-start cost: every
-fresh process pays seconds of XLA compilation before the first query is
-answered, even though the computations are byte-identical across
-restarts.  Enabling ``jax_compilation_cache_dir`` persists compiled
-executables to disk, turning restart into a warm start — ROADMAP's
-"compile time as a first-class perf axis" slice.  CI jobs point
-``JAX_COMPILATION_CACHE_DIR`` at a cached directory for the same
-reason; the serving benchmark records first-compile vs warm-start rows
-(``gate:false`` — absolute compile times are runner-dependent).
+First-compile latency is a cold-start cost: every fresh process pays
+seconds to minutes of compilation (sorts at 1e6+ regions compile
+slowly) before the first answer, even though the computations are
+byte-identical across runs.  ``enable()`` persists compiled executables
+to one fixed directory, so a restart is a warm start.  The directory is
+part of the cache key in effect, so it never moves between runs:
+``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -17,46 +15,39 @@ from pathlib import Path
 
 import jax
 
-DEFAULT_DIR = os.path.join(
-    os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-    "repro-jax-cache")
+# src/repro/serve/compile_cache.py -> the checkout root
+DEFAULT_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 _enabled_dir: str | None = None
 
 
-def enable(cache_dir: str | None = None, *,
-           min_compile_time_secs: float = 0.0) -> str | None:
+def cache_dir(requested: str | None = None) -> str:
+    """The cache directory in effect: ``$JAX_COMPILATION_CACHE_DIR`` if
+    set (and then no other), else ``requested``, else ``DEFAULT_DIR``."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR") or requested
+            or DEFAULT_DIR)
+
+
+def enable(requested: str | None = None, *,
+           min_compile_time_secs: float = 0.0) -> str:
     """Enable the persistent compilation cache (idempotent).
 
-    Directory precedence: explicit argument, ``$JAX_COMPILATION_CACHE_DIR``,
-    then a per-user default.  ``min_compile_time_secs=0`` caches every
-    executable — serving-scale query kernels compile fast but often, so
-    the default 1 s threshold would skip exactly the entries a restart
-    wants.  Returns the directory in effect, or ``None`` when this JAX
-    build exposes no compilation-cache config (the feature degrades to
-    a no-op rather than failing the server).
+    ``min_compile_time_secs=0`` caches every executable — query kernels
+    compile fast but often, so the default 1 s threshold would skip
+    exactly the entries a restart wants.  Returns the directory in
+    effect (see ``cache_dir``).
     """
     global _enabled_dir
-    cache_dir = (cache_dir
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or DEFAULT_DIR)
-    if _enabled_dir == cache_dir:
-        return _enabled_dir
-    try:
-        Path(cache_dir).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except (AttributeError, OSError):
-        return None
-    for opt, val in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             min_compile_time_secs),
-            ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(opt, val)
-        except AttributeError:
-            pass   # older JAX: the dir alone still enables the cache
-    _enabled_dir = cache_dir
-    return _enabled_dir
+    path = cache_dir(requested)
+    if _enabled_dir == path:
+        return path
+    Path(path).mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_time_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _enabled_dir = path
+    return path
 
 
 def enabled_dir() -> str | None:

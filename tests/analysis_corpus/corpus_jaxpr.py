@@ -76,12 +76,10 @@ def _dtype_contract(report, target):
 
 
 def _f64_promotion(report, target):
-    from jax.experimental import enable_x64
-
     def hot_path(x):
         return x.astype(jnp.float64).cumsum()
 
-    with enable_x64():
+    with jax.enable_x64(True):
         audit_fn(hot_path, (jax.ShapeDtypeStruct((64,), jnp.float32),),
                  target=target, report=report, check_rank=False)
 

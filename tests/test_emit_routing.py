@@ -7,9 +7,9 @@ view; its decoded dense form is the bit-identical object).  These tests
 pin each route explicitly (so the kernel under test is the one that
 actually runs — ``last_emit_route`` proves it), drive the router across
 every byte threshold, and cross the *real* default thresholds with
-interpret-mode runs at n+m = 6e5 (past the old ~5.2e5 resident/VMEM
-fallback point), 2e6 (upper edge of the streaming route), and 2.2e6
-(the csr regime — past every dense Pallas route).
+interpret-mode runs at n+m = 4e5 (resident), 6e5 (past the ~4.66e5
+resident bound), 2e6 (streaming), and 4.4e6 (the csr regime — past
+every dense Pallas route).
 """
 import numpy as np
 import pytest
@@ -32,14 +32,16 @@ def test_route_policy_thresholds_exact():
     e = 8192
     n = m = e // 2
     need = ops.emit_route_bytes(n, m)
-    assert need["resident"] == 4 * (3 * (e + 1) + e)
-    assert need["streaming"] == 4 * e + 2 * 8 * (DEF_BLOCK + 256) * 4
+    lines = 2 * (DEF_BLOCK + 128)           # the two output lines
+    perms = 2 * (n + 256)                   # both padded permutations
+    assert need["resident"] == 4 * (8 * e + perms + lines)
+    assert need["streaming"] == 4 * (perms + lines)
     # resident/streaming boundary
     assert ops.choose_emit_route(n, m, budget=need["resident"]) \
         == "resident"
     assert ops.choose_emit_route(n, m, budget=need["resident"] - 1) \
         == "streaming"
-    assert need["csr"] == 4 * (8 * (DEF_BLOCK + 256) + 2 * DEF_BLOCK)
+    assert need["csr"] == 4 * (lines + 256)
     # streaming/csr boundary (csr is constant-footprint, so it backstops
     # streaming at any size where the window alone fits)
     assert ops.choose_emit_route(n, m, budget=need["streaming"]) \
@@ -55,17 +57,17 @@ def test_route_policy_thresholds_exact():
 
 
 def test_route_policy_default_budget_regimes():
-    """Default 8 MiB budget: the sizes the paper regime cares about."""
+    """Default 16 MiB budget: the sizes the paper regime cares about."""
     assert ops.choose_emit_route(1024, 1024) == "resident"
-    assert ops.choose_emit_route(250_000, 250_000) == "resident"  # 5e5
-    assert ops.choose_emit_route(300_000, 300_000) == "streaming"  # 6e5
+    assert ops.choose_emit_route(200_000, 200_000) == "resident"  # 4e5
+    assert ops.choose_emit_route(250_000, 250_000) == "streaming"  # 5e5
     assert ops.choose_emit_route(500_000, 500_000) == "streaming"  # 1e6
-    assert ops.choose_emit_route(1_000_000, 1_000_000) == "streaming"
-    assert ops.choose_emit_route(1_100_000, 1_100_000) == "csr"  # 2.2e6
+    assert ops.choose_emit_route(2_000_000, 2_000_000) == "streaming"
+    assert ops.choose_emit_route(2_100_000, 2_100_000) == "csr"  # 4.2e6
     assert ops.choose_emit_route(5_000_000, 5_000_000) == "csr"  # 1e7
     assert ops.choose_emit_route(50_000_000, 50_000_000) == "csr"  # 1e8
     # without the lazy view the policy still falls back to XLA
-    assert ops.choose_emit_route(1_100_000, 1_100_000,
+    assert ops.choose_emit_route(2_100_000, 2_100_000,
                                  dense_only=True) == "xla"
 
 
@@ -123,6 +125,21 @@ def test_auto_route_follows_budget():
         assert got_c == want_c
         np.testing.assert_array_equal(np.asarray(got_p),
                                       np.asarray(want_p))
+
+
+@pytest.mark.parametrize("route", ["resident", "streaming", "csr"])
+def test_emit_split_into_several_calls(monkeypatch, route):
+    """Past MAX_TILES tiles the emit runs as several kernel calls (the
+    per-tile scalars must fit SMEM); the slots stay bit-identical."""
+    from repro.kernels import emit
+    monkeypatch.setattr(emit, "MAX_TILES", 3)
+    S, U = paper_workload(seed=21, n_total=4000, alpha=4.0)
+    want_p, want_c = sbm_pairs(S, U, 5000)
+    got_p, got_c = ops.twopass_pairs_pallas(S, U, 5000, block=128,
+                                            interpret=True, route=route)
+    assert ops.last_emit_route() == route
+    assert got_c == want_c
+    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
 
 
 def test_emit_empty_grid_and_empty_sets():
@@ -212,16 +229,20 @@ def _policy_sizes():
 
 
 def test_emit_route_bytes_monotone_in_problem_size():
-    """Per route, modeled bytes never decrease as n+m grows — the
-    policy's budget comparison is only sound against a monotone model."""
+    """Per route, modeled bytes never decrease as n or m grows — the
+    policy's budget comparison is only sound against a monotone model.
+    (The model is exact to the lane-padded allocation, so the order is
+    componentwise: a different n/m split of the same n+m may differ by
+    one lane tile.)"""
+    sizes = _policy_sizes()
     for block in (DEF_BLOCK, 2048):
-        prev = {"resident": -1, "streaming": -1, "csr": -1}
-        for n, m in sorted(_policy_sizes(), key=lambda t: t[0] + t[1]):
-            need = ops.emit_route_bytes(n, m, block=block)
-            for route in ("resident", "streaming", "csr"):
-                assert need[route] >= prev[route], \
-                    (route, n, m, block, need, prev)
-                prev[route] = need[route]
+        need = {s: ops.emit_route_bytes(*s, block=block) for s in sizes}
+        for a in sizes:
+            for b in sizes:
+                if a[0] <= b[0] and a[1] <= b[1]:
+                    for route in ("resident", "streaming", "csr"):
+                        assert need[a][route] <= need[b][route], \
+                            (route, a, b, block)
 
 
 def test_route_flip_exactly_at_budget_boundary_property():
@@ -273,13 +294,13 @@ def test_max_pairs_zero_builds_no_kernel_on_any_route():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n_total,expect", [
-    (500_000, "resident"),    # just under the old ~5.24e5 VMEM ceiling
+    (400_000, "resident"),    # under the ~4.66e5 resident VMEM bound
     (600_000, "streaming"),   # past it: only the streaming kernel fits
-    (2_200_000, "csr"),       # past the dense routes: csr decode view
+    (4_400_000, "csr"),       # past the dense routes: csr decode view
 ])
 def test_default_threshold_straddle_runs_pallas(n_total, expect):
-    """Above the old fallback threshold the *streaming kernel* (not the
-    XLA fallback) runs, and is bit-identical to the XLA pass 2."""
+    """At each default threshold the policy's kernel (not the XLA
+    fallback) runs, and is bit-identical to the XLA pass 2."""
     S, U = paper_workload(seed=29, n_total=n_total, alpha=0.02)
     assert ops.choose_emit_route(S.n, U.n) == expect
     cap = 2048

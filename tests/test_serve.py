@@ -406,16 +406,28 @@ def test_lm_serve_rename_stub_warns_and_forwards():
     assert stub.main is lm.main
 
 
-def test_compile_cache_enable_idempotent(tmp_path):
+def test_compile_cache_enable_idempotent(tmp_path, monkeypatch):
+    import os
+
     import jax
 
     from repro.serve import compile_cache
+    # without the variable: a fixed directory at the checkout root
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_DIR == os.path.join(root, ".jax_cache")
+    assert compile_cache.cache_dir() == compile_cache.DEFAULT_DIR
     d = str(tmp_path / "jaxcache")
     got = compile_cache.enable(d)
     assert got == d
     assert jax.config.jax_compilation_cache_dir == d
     assert compile_cache.enable(d) == d     # idempotent
     assert compile_cache.enabled_dir() == d
+    # with the variable set, that directory and no other
+    env = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    assert compile_cache.enable(d) == env
+    assert jax.config.jax_compilation_cache_dir == env
 
 
 def test_metrics_json_schema():

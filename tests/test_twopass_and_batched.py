@@ -92,6 +92,19 @@ def test_twopass_truncation_reports_exact_count():
     assert all(mask[s, u] for s, u in arr)
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_saturating_prefix_exact_past_int32(seed):
+    """Pass 1's slot offsets saturate exactly at the capacity, even when
+    the true running total passes 2**31 and the int32 scan wraps."""
+    rng = np.random.default_rng(seed)
+    counts = rng.integers(0, 2**30, 64).astype(np.int32)
+    counts[rng.integers(0, 64, 8)] = 0
+    for lim in (1, 5 * 10**8, 2**31 - 1):
+        got = np.asarray(sbm.saturating_prefix(jnp.asarray(counts), lim))
+        want = np.minimum(np.cumsum(counts.astype(np.int64)), lim)
+        np.testing.assert_array_equal(got, want)
+
+
 def test_count_dd_no_overflow_with_small_max_pairs():
     """The old d>1 path raised OverflowError when the candidate count
     exceeded a user-passed max_pairs; now the exact bound wins."""
