@@ -16,6 +16,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .hostread import host_sum
 from .regions import Regions
 
 Array = jax.Array
@@ -61,10 +62,7 @@ def bfm_count_per_sub(S: Regions, U: Regions, tile: int = 4096) -> Array:
 
 def bfm_count(S: Regions, U: Regions, tile: int = 4096) -> int:
     """Total number of overlapping (s, u) pairs (python int, exact)."""
-    import numpy as np
-
-    return int(np.sum(np.asarray(bfm_count_per_sub(S, U, tile=tile)),
-                      dtype=np.int64))
+    return host_sum(bfm_count_per_sub(S, U, tile=tile))
 
 
 @partial(jax.jit, static_argnames=("max_pairs",))

@@ -65,6 +65,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import itm
+from .hostread import host_sum, to_host
 from .regions import Regions
 from .sbm import saturating_prefix
 
@@ -104,7 +105,7 @@ def sample_splitters(v, tot: int, nshards: int,
     qs = np.zeros((nshards - 1,), np.float32)
     if tot > 0:
         stride = max(tot // max_sample, 1)
-        sample = np.asarray(v[:tot:stride], dtype=np.float64)
+        sample = to_host(v[:tot:stride], np.float64)
         sample = sample[np.isfinite(sample)]
         if sample.size:
             qs = np.quantile(
@@ -166,30 +167,34 @@ def _bucket_exchange(splitters, v, payloads, *, cap: int, nshards: int):
     payload (fill 0): dropped and padded slots are indistinguishable
     from real data otherwise.
     """
-    bucket = jnp.searchsorted(splitters, v, side="right").astype(jnp.int32)
-    valid = payloads[-1][0]            # by convention the last payload
-    bucket = jnp.where(valid > 0, bucket, nshards - 1)
-    order = jnp.argsort(bucket, stable=True)
-    b_sorted = bucket[order]
-    starts = jnp.searchsorted(b_sorted, jnp.arange(nshards, dtype=jnp.int32),
-                              side="left")
-    rank = jnp.arange(b_sorted.shape[0], dtype=jnp.int32) - starts[b_sorted]
-    overflow = jnp.any((rank >= cap) & (valid[order] > 0)).astype(jnp.int32)
-    ok = rank < cap
-    dst_b = jnp.where(ok, b_sorted, nshards)       # OOB => dropped
-    dst_r = jnp.where(ok, rank, cap)
+    with jax.named_scope("ddm.exchange"):
+        bucket = jnp.searchsorted(splitters, v,
+                                  side="right").astype(jnp.int32)
+        valid = payloads[-1][0]        # by convention the last payload
+        bucket = jnp.where(valid > 0, bucket, nshards - 1)
+        order = jnp.argsort(bucket, stable=True)
+        b_sorted = bucket[order]
+        starts = jnp.searchsorted(
+            b_sorted, jnp.arange(nshards, dtype=jnp.int32), side="left")
+        rank = (jnp.arange(b_sorted.shape[0], dtype=jnp.int32)
+                - starts[b_sorted])
+        overflow = jnp.any((rank >= cap)
+                           & (valid[order] > 0)).astype(jnp.int32)
+        ok = rank < cap
+        dst_b = jnp.where(ok, b_sorted, nshards)       # OOB => dropped
+        dst_r = jnp.where(ok, rank, cap)
 
-    def send(x, fill):
-        buf = jnp.full((nshards, cap), fill, x.dtype)
-        return buf.at[dst_b, dst_r].set(x[order], mode="drop")
+        def send(x, fill):
+            buf = jnp.full((nshards, cap), fill, x.dtype)
+            return buf.at[dst_b, dst_r].set(x[order], mode="drop")
 
-    def xchg(x):
-        return jax.lax.all_to_all(x, AXIS, split_axis=0,
-                                  concat_axis=0).reshape(-1)
+        def xchg(x):
+            return jax.lax.all_to_all(x, AXIS, split_axis=0,
+                                      concat_axis=0).reshape(-1)
 
-    received = [xchg(send(v, jnp.inf))]
-    received.extend(xchg(send(x, fill)) for x, fill in payloads)
-    return received, overflow
+        received = [xchg(send(v, jnp.inf))]
+        received.extend(xchg(send(x, fill)) for x, fill in payloads)
+        return received, overflow
 
 
 def _endpoints_flat(S: Regions, U: Regions):
@@ -283,10 +288,10 @@ def _distributed_count(S: Regions, U: Regions, mesh: Mesh | None = None,
     parts, overflow = _dist_count(v, is_lo, is_upd, valid, splitters,
                                   nshards=nshards, cap=cap,
                                   blk=_count_block(tot), mesh=mesh)
-    if int(np.max(np.asarray(overflow))) > 0:
+    if host_sum(overflow) > 0:
         raise OverflowError(
             "distributed SBM bucket overflow; raise overprovision")
-    return int(np.sum(np.asarray(parts), dtype=np.int64))
+    return host_sum(parts)
 
 
 # ---------------------------------------------------------------------------

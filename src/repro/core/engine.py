@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import brute, grid, itm, sbm
+from .hostread import host_sum, to_host
 from .pairs import DensePairs, PairsResult, ShardedPairs
 from .regions import Regions
 
@@ -201,7 +202,8 @@ class MatchPlan:
                 f" d={self.d}); got (n_sub={S.n}, n_upd={U.n}, d={S.d})")
 
     def _jitted(self, name: str, fn, static_argnames=()):
-        """Per-plan jitted executable with a trace counter."""
+        """Per-plan jitted executable with a trace counter, named
+        ``plan_<name>`` so its programs show as ``jit_plan_<name>``."""
         cached = self._exec.get(name)
         if cached is None:
             plan = self
@@ -211,6 +213,7 @@ class MatchPlan:
                 plan.trace_log.append(name)
                 return fn(*args, **kw)
 
+            counting.__name__ = counting.__qualname__ = f"plan_{name}"
             cached = jax.jit(counting, static_argnames=static_argnames)
             if _JIT_CAPTURE_HOOK is not None:
                 cached = _JIT_CAPTURE_HOOK(self, name, fn, static_argnames,
@@ -263,6 +266,7 @@ class MatchPlan:
         return Regions(R.lo[:, :1], R.hi[:, :1])
 
     # -- counting -----------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="ddm.count")
     def count(self, S: Regions, U: Regions) -> int:
         """Exact number of overlapping (subscription, update) pairs."""
         self._check(S, U)
@@ -292,7 +296,7 @@ class MatchPlan:
         f = self._jitted(
             "bfm_count",
             functools.partial(brute.bfm_count_per_sub, tile=spec.tile))
-        return int(np.sum(np.asarray(f(S, U)), dtype=np.int64))
+        return host_sum(f(S, U))
 
     def _count_1d(self, S: Regions, U: Regions) -> int:
         spec = self.spec
@@ -306,15 +310,15 @@ class MatchPlan:
         if algo == "sbm":
             f = self._jitted("sbm_contribs", sbm._sweep_contribs)
             c = f(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0])
-            return int(np.sum(np.asarray(c), dtype=np.int64))
+            return host_sum(c)
         if algo == "sbm_chunked":
             f = self._jitted("sbm_chunked", sbm._chunked_contribs,
                              static_argnames=("p",))
             c = f(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0], p=spec.p)
-            return int(np.sum(np.asarray(c), dtype=np.int64))
+            return host_sum(c)
         if algo == "sbm_binary":
             f = self._jitted("sbm_per_sub", sbm.sbm_count_per_sub)
-            return int(np.sum(np.asarray(f(S, U)), dtype=np.int64))
+            return host_sum(f(S, U))
         if algo == "itm":
             build_on_S = (S.n <= U.n if spec.swap == "auto"
                           else spec.swap == "S")
@@ -322,7 +326,7 @@ class MatchPlan:
             Q = U if build_on_S else S
             f = self._jitted("itm_counts", itm.itm_query_counts)
             c = f(T, Q.lo[:, 0], Q.hi[:, 0])
-            return int(np.sum(np.asarray(c), dtype=np.int64))
+            return host_sum(c)
         if algo == "gbm":
             return grid.gbm_count(S, U, ncells=spec.ncells)
         raise AssertionError(algo)
@@ -359,7 +363,7 @@ class MatchPlan:
             f = self._jitted("hsbm_tables", sbm._hsbm_phase1,
                              static_argnames=_HSBM_STATIC_ARGNAMES)
             counts = f(*args, max_pairs=1, **g.statics())[3]
-        return int(np.sum(np.asarray(counts), dtype=np.int64))
+        return host_sum(counts)
 
     def _count_distributed(self, S: Regions, U: Regions) -> int:
         spec = self.spec
@@ -372,6 +376,7 @@ class MatchPlan:
                                   overprovision=spec.overprovision)
 
     # -- pair enumeration ---------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="ddm.pairs")
     def pairs(self, S: Regions, U: Regions):
         """Enumerate overlaps: ``(PairsResult, count)``.
 
@@ -453,14 +458,13 @@ class MatchPlan:
         f = self._jitted("verify", sbm_verify_dims,
                          static_argnames=("max_pairs",))
         pairs, count = f(S, U, cand, max_pairs=out_cap)
-        return pairs, int(count)
+        return pairs, host_sum(count)
 
     def _cand_bound(self, S: Regions, U: Regions) -> int:
         """Exact dim-0 candidate count (binary-search per-sub counts)."""
         f = self._jitted("cand_per_sub", sbm.sbm_count_per_sub)
         c = f(self._project(S), self._project(U))
-        return self._resolve_cand_cap(
-            int(np.sum(np.asarray(c), dtype=np.int64)))
+        return self._resolve_cand_cap(host_sum(c))
 
     def _pairs_bfm(self, S: Regions, U: Regions, out_cap: int):
         spec = self.spec
@@ -469,11 +473,11 @@ class MatchPlan:
             pairs, count = ops.bfm_pairs_pallas(
                 S, U, out_cap, ts=spec.ts, tu=spec.tu,
                 interpret=spec.interpret)
-            return pairs, int(count)
+            return pairs, count
         f = self._jitted("bfm_pairs", brute.bfm_pairs,
                          static_argnames=("max_pairs",))
         pairs, count = f(S, U, max_pairs=out_cap)
-        return pairs, int(count)
+        return pairs, host_sum(count)
 
     def validate_pairs(self, pairs, count: int | None = None) -> None:
         """Host-side sanity check of a ``pairs()`` result buffer.
@@ -557,9 +561,7 @@ class MatchPlan:
                          static_argnames=("max_pairs",))
         pairs, cnt_a, cnt_b = f(S0.lo[:, 0], S0.hi[:, 0],
                                 U0.lo[:, 0], U0.hi[:, 0], max_pairs=cap)
-        k = int(np.sum(np.asarray(cnt_a), dtype=np.int64)
-                + np.sum(np.asarray(cnt_b), dtype=np.int64))
-        return pairs, k
+        return pairs, host_sum(cnt_a, cnt_b)
 
     def _pairs_hsbm_dim0(self, S: Regions, U: Regions, cap: int):
         """Hybrid grid+SBM dim-0 enumeration (measured geometry)."""
@@ -581,21 +583,19 @@ class MatchPlan:
                           U0.hi[:, 0], jnp.float32(g.lb),
                           jnp.float32(g.width), max_pairs=cap,
                           **g.statics())
-        k = int(np.sum(np.asarray(counts), dtype=np.int64))
-        return pairs, k
+        return pairs, host_sum(counts)
 
     def _pairs_itm_dim0(self, S: Regions, U: Regions, cap: int):
         T = itm.build_tree(self._project(S))
         fc = self._jitted("itm_counts", itm.itm_query_counts)
-        counts = fc(T, U.lo[:, 0], U.hi[:, 0])
-        per_q = max(int(np.max(np.asarray(counts), initial=0)), 1)
+        counts = to_host(fc(T, U.lo[:, 0], U.hi[:, 0]))
+        per_q = max(int(counts.max(initial=0)), 1)
         if self.spec.capacity == "grow":   # bound retraces under drift
             per_q = _pow2(per_q)
         fp = self._jitted("itm_flatten", itm_flatten_pairs,
                           static_argnames=("per_q", "cap"))
         cand = fp(T, U.lo[:, 0], U.hi[:, 0], per_q=per_q, cap=cap)
-        k = int(np.sum(np.asarray(counts), dtype=np.int64))
-        return cand, k
+        return cand, int(np.sum(counts, dtype=np.int64))
 
     def _pairs_distributed(self, S: Regions, U: Regions, out_cap: int):
         """Sharded two-pass emit with per-device slot-bound buffers.
@@ -633,10 +633,10 @@ class MatchPlan:
             cap_s=dist.bucket_cap(S.n, nshards, spec.overprovision),
             cap_u=dist.bucket_cap(U.n, nshards, spec.overprovision),
             nshards=nshards, mesh=mesh)
-        if int(np.asarray(ovf)) > 0:
+        if host_sum(ovf) > 0:
             raise OverflowError(
                 "distributed SBM bucket overflow; raise overprovision")
-        counts_h = np.asarray(counts)
+        counts_h = to_host(counts)
         k0 = int(np.sum(counts_h, dtype=np.int64))
         dev_tot = counts_h.reshape(nshards, -1).sum(axis=1,
                                                     dtype=np.int64)
@@ -646,7 +646,7 @@ class MatchPlan:
         bufs, ver = f2(S.lo, S.hi, U.lo, U.hi, u_sorted, s_sorted,
                        perm_s, perm_u, cap_dev=cap_dev, nshards=nshards,
                        mesh=mesh)
-        ver_h = np.asarray(ver, dtype=np.int64)
+        ver_h = to_host(ver, np.int64)
         k = k0 if self.d == 1 else int(ver_h.sum())
         return ShardedPairs(bufs, ver_h, out_cap, k), k
 
@@ -669,6 +669,7 @@ class MatchPlan:
         return f(S, U)
 
     # -- dynamic-service batched query (paper §3) ---------------------------
+    @functools.partial(jax.profiler.annotate_function, name="ddm.query")
     def query(self, tree: itm.ITree, opp: Regions, q_lo: Array,
               q_hi: Array):
         """Verified d-dim overlap ids for a batch of query boxes.
@@ -693,7 +694,7 @@ class MatchPlan:
         fc = self._jitted("itm_counts", itm.itm_query_counts)
         counts0 = fc(tree, q_lo[:, 0], q_hi[:, 0])
         cap = self._resolve_query_cap(
-            int(np.max(np.asarray(counts0), initial=0)))
+            int(to_host(counts0).max(initial=0)))
         fq = self._jitted("itm_query_dd", itm.itm_query_pairs_dd,
                           static_argnames=("cap",))
         return fq(tree, opp.lo, opp.hi, q_lo, q_hi, cap=cap)
@@ -720,7 +721,7 @@ class MatchPlan:
                      mesh=mesh)
         # global max-count reduction: one shared static capacity
         cap = self._resolve_query_cap(
-            int(np.max(np.asarray(counts0), initial=0)))
+            int(to_host(counts0).max(initial=0)))
         fq = self._jitted("dist_query", dist._dist_query,
                           static_argnames=("cap", "nshards", "mesh"))
         return fq(tree, opp.lo, opp.hi, q_lo, q_hi, cap=cap,
@@ -782,16 +783,17 @@ def describe_pair_range_errors(arr: np.ndarray, m: int,
 
 def sbm_verify_dims(S: Regions, U: Regions, cand: Array, max_pairs: int):
     """Filter dim-0 candidate pairs on dimensions 1..d-1, recompact."""
-    s_idx, u_idx = cand[:, 0], cand[:, 1]
-    valid = s_idx >= 0
-    si = jnp.maximum(s_idx, 0)
-    ui = jnp.maximum(u_idx, 0)
-    ok = jnp.all(
-        jnp.logical_and(S.lo[si, 1:] < U.hi[ui, 1:],
-                        U.lo[ui, 1:] < S.hi[si, 1:]), axis=-1)
-    ok = ok & valid
-    count = jnp.sum(ok, dtype=jnp.int32)
-    return select_rows(cand, ok, max_pairs), count
+    with jax.named_scope("ddm.verify"):
+        s_idx, u_idx = cand[:, 0], cand[:, 1]
+        valid = s_idx >= 0
+        si = jnp.maximum(s_idx, 0)
+        ui = jnp.maximum(u_idx, 0)
+        ok = jnp.all(
+            jnp.logical_and(S.lo[si, 1:] < U.hi[ui, 1:],
+                            U.lo[ui, 1:] < S.hi[si, 1:]), axis=-1)
+        ok = ok & valid
+        count = jnp.sum(ok, dtype=jnp.int32)
+        return select_rows(cand, ok, max_pairs), count
 
 
 def itm_flatten_pairs(T: itm.ITree, q_lo: Array, q_hi: Array, per_q: int,

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .hostread import host_sum, to_host
 from .regions import Regions
 
 Array = jax.Array
@@ -115,7 +116,7 @@ def _capacities(lo, hi, lb, width, ncells):
     """Host-side pre-pass: max cells per region, max regions per cell."""
     c0, c1 = _cell_spans(jnp.asarray(lo), jnp.asarray(hi),
                          jnp.float32(lb), jnp.float32(width), ncells)
-    c0n, c1n = np.asarray(c0), np.asarray(c1)
+    c0n, c1n = to_host(c0), to_host(c1)
     span = int((c1n - c0n).max()) + 1
     # occupancy per cell via difference array
     diff = np.bincount(c0n, minlength=ncells + 1).astype(np.int64)
@@ -214,10 +215,10 @@ def hsbm_geometry(s_lo, s_hi, u_lo, u_hi,
     float64 so rounding can only widen the suffix, never miss a
     boundary-crossing region.
     """
-    s_lo = np.asarray(s_lo, np.float32)
-    s_hi = np.asarray(s_hi, np.float32)
-    u_lo = np.asarray(u_lo, np.float32)
-    u_hi = np.asarray(u_hi, np.float32)
+    s_lo = to_host(s_lo, np.float32)
+    s_hi = to_host(s_hi, np.float32)
+    u_lo = to_host(u_lo, np.float32)
+    u_hi = to_host(u_hi, np.float32)
     n, m = s_lo.shape[0], u_lo.shape[0]
     lb = float(min(s_lo.min(), u_lo.min()))
     top = float(max(s_hi.max(), u_hi.max()))
@@ -265,8 +266,8 @@ def gbm_count(S: Regions, U: Regions, ncells: int = 3000,
               chunk: int | None = None) -> int:
     """Total K via grid matching.  ``ncells`` is the paper's tuning knob."""
     assert S.d == 1
-    lb = float(min(jnp.min(S.lo), jnp.min(U.lo)))
-    ub = float(max(jnp.max(S.hi), jnp.max(U.hi)))
+    lb = float(min(to_host(jnp.min(S.lo)), to_host(jnp.min(U.lo))))
+    ub = float(max(to_host(jnp.max(S.hi)), to_host(jnp.max(U.hi))))
     width = max((ub - lb) / ncells, 1e-30)
     span_s, cap_s = _capacities(S.lo[:, 0], S.hi[:, 0], lb, width, ncells)
     span_u, cap_u = _capacities(U.lo[:, 0], U.hi[:, 0], lb, width, ncells)
@@ -277,4 +278,4 @@ def gbm_count(S: Regions, U: Regions, ncells: int = 3000,
         chunk -= 1
     counts = _gbm_cell_counts(S, U, jnp.float32(lb), jnp.float32(width),
                               ncells, cap_s, cap_u, span_s, span_u, chunk)
-    return int(np.sum(np.asarray(counts), dtype=np.int64))
+    return host_sum(counts)

@@ -36,8 +36,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from .hostread import host_sum
 from .regions import Regions
 
 Array = jax.Array
@@ -226,5 +226,4 @@ def itm_count(S: Regions, U: Regions, swap: str = "auto") -> int:
     build_on_S = S.n <= U.n if swap == "auto" else (swap == "S")
     T = build_tree(S if build_on_S else U)
     Q = U if build_on_S else S
-    counts = itm_query_counts(T, Q.lo[:, 0], Q.hi[:, 0])
-    return int(np.sum(np.asarray(counts), dtype=np.int64))
+    return host_sum(itm_query_counts(T, Q.lo[:, 0], Q.hi[:, 0]))

@@ -34,8 +34,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from .hostread import host_sum
 from .regions import Regions
 
 Array = jax.Array
@@ -93,8 +93,8 @@ def sbm_count_sweep(S: Regions, U: Regions) -> int:
     in the engine's match-then-verify path (``engine.MatchPlan``).
     """
     assert S.d == 1, "sbm_count_sweep is the 1-D primitive (see dd_match)"
-    c = _sweep_contribs(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0])
-    return int(np.sum(np.asarray(c), dtype=np.int64))
+    return host_sum(_sweep_contribs(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0],
+                                    U.hi[:, 0]))
 
 
 # ---------------------------------------------------------------------------
@@ -144,8 +144,8 @@ def _chunked_contribs(s_lo, s_hi, u_lo, u_hi, p: int) -> Array:
 
 def sbm_count_chunked(S: Regions, U: Regions, p: int = 8) -> int:
     assert S.d == 1
-    c = _chunked_contribs(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0], p)
-    return int(np.sum(np.asarray(c), dtype=np.int64))
+    return host_sum(_chunked_contribs(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0],
+                                      U.hi[:, 0], p))
 
 
 # ---------------------------------------------------------------------------
@@ -169,8 +169,7 @@ def sbm_count_per_sub(S: Regions, U: Regions) -> Array:
 
 
 def sbm_count_binary(S: Regions, U: Regions) -> int:
-    c = sbm_count_per_sub(S, U)
-    return int(np.sum(np.asarray(c), dtype=np.int64))
+    return host_sum(sbm_count_per_sub(S, U))
 
 
 # ---------------------------------------------------------------------------
@@ -222,29 +221,34 @@ def _twopass_phase1(s_lo, s_hi, u_lo, u_hi, max_pairs: int):
     Shared by the XLA pass-2 (``_twopass_emit``) and the fused Pallas
     emit kernel (``kernels.ops.twopass_pairs_pallas``).
     """
-    perm_u = jnp.argsort(u_lo).astype(jnp.int32)
-    perm_s = jnp.argsort(s_lo).astype(jnp.int32)
-    u_lo_sorted = u_lo[perm_u]
-    s_lo_sorted = s_lo[perm_s]
+    with jax.named_scope("ddm.pass1.sort"):
+        perm_u = jnp.argsort(u_lo).astype(jnp.int32)
+        perm_s = jnp.argsort(s_lo).astype(jnp.int32)
+        u_lo_sorted = u_lo[perm_u]
+        s_lo_sorted = s_lo[perm_s]
 
     # exact per-emitter counts (A: one emitter per s; B: per u)
-    aA = jnp.searchsorted(u_lo_sorted, s_lo, side="left").astype(jnp.int32)
-    rA = jnp.searchsorted(u_lo_sorted, s_hi, side="left").astype(jnp.int32)
-    bB = jnp.searchsorted(s_lo_sorted, u_lo, side="right").astype(jnp.int32)
-    cB = jnp.searchsorted(s_lo_sorted, u_hi, side="left").astype(jnp.int32)
-    # the maximum(·, 0) guards the offsets scan against degenerate
-    # (empty, lo == hi) intervals, which violate the module precondition
-    # but must not corrupt emission for the well-formed regions
-    cnt_a = jnp.maximum(rA - aA, 0)                        # (n,)
-    cnt_b = jnp.maximum(cB - bB, 0)                        # (m,)
+    with jax.named_scope("ddm.pass1.search"):
+        aA = jnp.searchsorted(u_lo_sorted, s_lo, side="left").astype(jnp.int32)
+        rA = jnp.searchsorted(u_lo_sorted, s_hi, side="left").astype(jnp.int32)
+        bB = jnp.searchsorted(s_lo_sorted, u_lo,
+                              side="right").astype(jnp.int32)
+        cB = jnp.searchsorted(s_lo_sorted, u_hi, side="left").astype(jnp.int32)
+        # the maximum(·, 0) guards the offsets scan against degenerate
+        # (empty, lo == hi) intervals, which violate the module
+        # precondition but must not corrupt emission for the well-formed
+        # regions
+        cnt_a = jnp.maximum(rA - aA, 0)                        # (n,)
+        cnt_b = jnp.maximum(cB - bB, 0)                        # (m,)
 
     # exclusive-scan offsets, saturating at max_pairs: offsets below the
     # buffer limit stay exact; emitters wholly past it land on the limit
     # and are never selected by the slot lookup.
-    starts = jnp.concatenate([aA, bB])
-    counts = jnp.concatenate([cnt_a, cnt_b])
-    incl = saturating_prefix(counts, max_pairs)
-    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
+    with jax.named_scope("ddm.pass1.scan"):
+        starts = jnp.concatenate([aA, bB])
+        counts = jnp.concatenate([cnt_a, cnt_b])
+        incl = saturating_prefix(counts, max_pairs)
+        offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
     return perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b
 
 
@@ -362,40 +366,44 @@ def _hsbm_phase1(s_lo, s_hi, u_lo, u_hi, lb, width, *, ncells: int,
     the same contract the flat ``_twopass_phase1`` feeds to pass 2.
     """
     n, m = s_lo.shape[0], u_lo.shape[0]
-    s_nat_bits, s_emit_bits, s_emit_ids = _hsbm_side_tables(
-        s_lo, s_hi, lb, width, ncells, cap_s, suf_s)
-    u_nat_bits, u_emit_bits, u_emit_ids = _hsbm_side_tables(
-        u_lo, u_hi, lb, width, ncells, cap_u, suf_u)
+    with jax.named_scope("ddm.pass1.sort"):
+        s_nat_bits, s_emit_bits, s_emit_ids = _hsbm_side_tables(
+            s_lo, s_hi, lb, width, ncells, cap_s, suf_s)
+        u_nat_bits, u_emit_bits, u_emit_ids = _hsbm_side_tables(
+            u_lo, u_hi, lb, width, ncells, cap_u, suf_u)
     ss_l = jax.vmap(partial(jnp.searchsorted, side="left"))
     ss_r = jax.vmap(partial(jnp.searchsorted, side="right"))
 
-    # class A: u.lo ∈ [s.lo, s.hi) — window of U natives per S emitter
-    s_emit_hi = jnp.where(
-        s_emit_ids >= 0,
-        jnp.take(s_hi, jnp.clip(s_emit_ids, 0, n - 1)), jnp.inf)
-    aA = ss_l(u_nat_bits, s_emit_bits).astype(jnp.int32)
-    rA = ss_l(u_nat_bits, _sortable_bits(s_emit_hi)).astype(jnp.int32)
-    cnt_a = jnp.maximum(rA - aA, 0)
-    # class B: u.lo < s.lo < u.hi — strict-stab window of S natives per
-    # U emitter (side="right" excludes s.lo == u.lo, already class A)
-    u_emit_hi = jnp.where(
-        u_emit_ids >= 0,
-        jnp.take(u_hi, jnp.clip(u_emit_ids, 0, m - 1)), -jnp.inf)
-    bB = ss_r(s_nat_bits, u_emit_bits).astype(jnp.int32)
-    cB = ss_l(s_nat_bits, _sortable_bits(u_emit_hi)).astype(jnp.int32)
-    cnt_b = jnp.maximum(cB - bB, 0)
+    with jax.named_scope("ddm.pass1.search"):
+        # class A: u.lo ∈ [s.lo, s.hi) — window of U natives per S emitter
+        s_emit_hi = jnp.where(
+            s_emit_ids >= 0,
+            jnp.take(s_hi, jnp.clip(s_emit_ids, 0, n - 1)), jnp.inf)
+        aA = ss_l(u_nat_bits, s_emit_bits).astype(jnp.int32)
+        rA = ss_l(u_nat_bits, _sortable_bits(s_emit_hi)).astype(jnp.int32)
+        cnt_a = jnp.maximum(rA - aA, 0)
+        # class B: u.lo < s.lo < u.hi — strict-stab window of S natives
+        # per U emitter (side="right" excludes s.lo == u.lo, already
+        # class A)
+        u_emit_hi = jnp.where(
+            u_emit_ids >= 0,
+            jnp.take(u_hi, jnp.clip(u_emit_ids, 0, m - 1)), -jnp.inf)
+        bB = ss_r(s_nat_bits, u_emit_bits).astype(jnp.int32)
+        cB = ss_l(s_nat_bits, _sortable_bits(u_emit_hi)).astype(jnp.int32)
+        cnt_b = jnp.maximum(cB - bB, 0)
 
     # globalize window starts into the flat emitter index space of the
     # opposite side (row stride = natives + suffix width); windows only
     # ever cover native columns [0, cap), which occupy the row prefix
     cap_e_u = cap_u + suf_u
     cap_e_s = cap_s + suf_s
-    rows = jnp.arange(ncells, dtype=jnp.int32)[:, None]
-    starts = jnp.concatenate([(aA + rows * cap_e_u).reshape(-1),
-                              (bB + rows * cap_e_s).reshape(-1)])
-    counts = jnp.concatenate([cnt_a.reshape(-1), cnt_b.reshape(-1)])
-    incl = saturating_prefix(counts, max_pairs)
-    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
+    with jax.named_scope("ddm.pass1.scan"):
+        rows = jnp.arange(ncells, dtype=jnp.int32)[:, None]
+        starts = jnp.concatenate([(aA + rows * cap_e_u).reshape(-1),
+                                  (bB + rows * cap_e_s).reshape(-1)])
+        counts = jnp.concatenate([cnt_a.reshape(-1), cnt_b.reshape(-1)])
+        incl = saturating_prefix(counts, max_pairs)
+        offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl])
     return (s_emit_ids.reshape(-1), u_emit_ids.reshape(-1),
             starts, counts, offs)
 
@@ -453,8 +461,7 @@ def hsbm_pairs(S: Regions, U: Regions, max_pairs: int,
     pairs, counts = _hsbm_emit(
         s_lo, s_hi, u_lo, u_hi, jnp.float32(g.lb), jnp.float32(g.width),
         max_pairs=max_pairs, **g.statics())
-    count = int(np.sum(np.asarray(counts), dtype=np.int64))
-    return pairs, count
+    return pairs, host_sum(counts)
 
 
 def sbm_pairs(S: Regions, U: Regions, max_pairs: int):
@@ -473,6 +480,4 @@ def sbm_pairs(S: Regions, U: Regions, max_pairs: int):
         return jnp.full((max_pairs, 2), -1, jnp.int32), 0
     pairs, cnt_a, cnt_b = _twopass_emit(
         S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0], max_pairs)
-    count = int(np.sum(np.asarray(cnt_a), dtype=np.int64)
-                + np.sum(np.asarray(cnt_b), dtype=np.int64))
-    return pairs, count
+    return pairs, host_sum(cnt_a, cnt_b)

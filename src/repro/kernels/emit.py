@@ -397,8 +397,9 @@ def _emit_slots(tab, perm_s_pad, perm_u_pad, w0, *, n: int, nslots: int,
     halves = []
     for c0 in range(0, nt, MAX_TILES):
         nc = min(MAX_TILES, nt - c0)
-        meta = tile_meta(tab[0], w0 + c0 * block, nt=nc, block=block,
-                         win=win, limit=limit)
+        with jax.named_scope("ddm.emit.pack"):
+            meta = tile_meta(tab[0], w0 + c0 * block, nt=nc, block=block,
+                             win=win, limit=limit)
         halves.append(emit_call(meta, tab, perm_s_pad, perm_u_pad, n=n,
                                 nt=nc, block=block, mode=mode,
                                 interpret=interpret))
@@ -412,8 +413,9 @@ def _dense_emit(offs, counts, starts, perm_s, perm_u, *, n: int, m: int,
     if max_pairs == 0:
         return _empty_pairs()
     bl = min(lane_pad(block), max(128, lane_pad(max_pairs)))
-    tab = pack_emitter_tables(offs, counts, starts, n=n, m=m,
-                              min_len=stream_window(bl))
+    with jax.named_scope("ddm.emit.pack"):
+        tab = pack_emitter_tables(offs, counts, starts, n=n, m=m,
+                                  min_len=stream_window(bl))
     return _emit_slots(tab, pad_perm(perm_s), pad_perm(perm_u),
                        jnp.int32(0), n=n, nslots=max_pairs, block=bl,
                        mode=mode, interpret=interpret, limit=max_pairs)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.hostread import host_sum
 from ..core.pairs import PairsResult
 from ..core.regions import Regions
 from ..core.sbm import _endpoint_stream, _hsbm_phase1, _twopass_phase1
@@ -46,7 +47,7 @@ def bfm_count_pallas(S: Regions, U: Regions, *, ts: int = 256,
     if S.n == 0 or U.n == 0:
         return 0
     tiles = _tile_counts(S.lo, S.hi, U.lo, U.hi, ts, tu, interpret)
-    return int(np.sum(np.asarray(tiles), dtype=np.int64))
+    return host_sum(tiles)
 
 
 @functools.partial(jax.jit, static_argnames=("ts", "tu", "interpret"))
@@ -99,7 +100,7 @@ def bfm_pairs_pallas(S: Regions, U: Regions, max_pairs: int, *,
             "two-pass emit path at this scale (MatchSpec(algo='sbm')).")
     mask = bfm_mask_pallas(S, U, ts=ts, tu=tu, interpret=interpret)
     pairs, count = _compact_mask_pairs(mask, max_pairs)
-    return pairs, int(count)
+    return pairs, host_sum(count)
 
 
 @functools.partial(jax.jit, static_argnames=("max_pairs",))
@@ -259,9 +260,10 @@ def _csr_tables(s_lo, s_hi, u_lo, u_hi, max_pairs, block):
     n, m = s_lo.shape[0], u_lo.shape[0]
     perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b = _twopass_phase1(
         s_lo, s_hi, u_lo, u_hi, max_pairs)
-    tab = emit_kernel.pack_emitter_tables(
-        offs, counts, starts, n=n, m=m,
-        min_len=emit_kernel.stream_window(block))
+    with jax.named_scope("ddm.emit.pack"):
+        tab = emit_kernel.pack_emitter_tables(
+            offs, counts, starts, n=n, m=m,
+            min_len=emit_kernel.stream_window(block))
     return (tab, emit_kernel.pad_perm(perm_s), emit_kernel.pad_perm(perm_u),
             cnt_a, cnt_b)
 
@@ -282,8 +284,7 @@ def twopass_pairs_csr(S: Regions, U: Regions, max_pairs: int, *,
                               interpret=interpret), 0
     tab, ps, pu, cnt_a, cnt_b = _csr_tables(
         S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0], max_pairs, block)
-    count = int(np.sum(np.asarray(cnt_a), dtype=np.int64)
-                + np.sum(np.asarray(cnt_b), dtype=np.int64))
+    count = host_sum(cnt_a, cnt_b)
     view = CSRPairs(tab, ps, pu, n=S.n, m=U.n, cap=max_pairs,
                     count=count, block=block, interpret=interpret)
     return view, count
@@ -341,9 +342,7 @@ def twopass_pairs_pallas(S: Regions, U: Regions, max_pairs: int, *,
             else emit_kernel.twopass_emit_streaming)
     pairs = emit(offs, counts, starts, perm_s, perm_u, n=S.n, m=U.n,
                  max_pairs=max_pairs, block=block, interpret=interpret)
-    count = int(np.sum(np.asarray(cnt_a), dtype=np.int64)
-                + np.sum(np.asarray(cnt_b), dtype=np.int64))
-    return pairs, count
+    return pairs, host_sum(cnt_a, cnt_b)
 
 
 # ---------------------------------------------------------------------------
@@ -377,9 +376,10 @@ def _hsbm_csr_tables(s_lo, s_hi, u_lo, u_hi, lb, width, *, ncells, cap_s,
         suf_s=suf_s, cap_u=cap_u, suf_u=suf_u, max_pairs=max_pairs)
     n_a = ncells * (cap_s + suf_s)
     n_b = ncells * (cap_u + suf_u)
-    tab = emit_kernel.pack_emitter_tables(
-        offs, counts, starts, n=n_a, m=n_b,
-        min_len=emit_kernel.stream_window(block))
+    with jax.named_scope("ddm.emit.pack"):
+        tab = emit_kernel.pack_emitter_tables(
+            offs, counts, starts, n=n_a, m=n_b,
+            min_len=emit_kernel.stream_window(block))
     return (tab, emit_kernel.pad_perm(sid + n_a),
             emit_kernel.pad_perm(uid + n_b), sid, uid, counts)
 
@@ -464,12 +464,12 @@ def hsbm_pairs_pallas(S: Regions, U: Regions, max_pairs: int, *,
         from ..core.sbm import _hsbm_emit
         pairs, counts = _hsbm_emit(s_lo, s_hi, u_lo, u_hi, lb, width,
                                    max_pairs=max_pairs, **geom.statics())
-        return pairs, int(np.sum(np.asarray(counts), dtype=np.int64))
+        return pairs, host_sum(counts)
     if route == "csr":
         tab, ps, pu, sid, uid, counts = _hsbm_csr_tables(
             s_lo, s_hi, u_lo, u_hi, lb, width, max_pairs=max_pairs,
             block=block, **geom.statics())
-        count = int(np.sum(np.asarray(counts), dtype=np.int64))
+        count = host_sum(counts)
         view = HsbmCSRPairs(tab, ps, pu, n=n_a, m=n_b, cap=max_pairs,
                             count=count, block=block, interpret=interpret,
                             sid=sid, uid=uid)
@@ -484,8 +484,7 @@ def hsbm_pairs_pallas(S: Regions, U: Regions, max_pairs: int, *,
                  interpret=interpret)
     pairs = emit_kernel.remap_slot_pairs(slots, sid, uid, n_a=n_a,
                                          n_b=n_b)
-    count = int(np.sum(np.asarray(counts), dtype=np.int64))
-    return pairs, count
+    return pairs, host_sum(counts)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -509,4 +508,4 @@ def sbm_count_pallas(S: Regions, U: Regions, *, block: int = 2048,
         return 0
     c = _sweep(S.lo[:, 0], S.hi[:, 0], U.lo[:, 0], U.hi[:, 0],
                block, interpret)
-    return int(np.sum(np.asarray(c), dtype=np.int64))
+    return host_sum(c)
