@@ -20,6 +20,11 @@ structure; all are bit-identical and cross-checked in tests:
   sorted arrays + searchsorted), which also yields *per-region* counts
   used by the dynamic DDM service.
 
+Pair enumeration (``sbm_pairs``) needs per-region ranks too; its pass 1
+reads all of them off one stable merged endpoint sort (``_merged_ranks``)
+rather than searching, since on a TPU each search step is a dependent
+random gather.
+
 Endpoint ordering: half-open intervals require upper endpoints to be
 processed *before* lower endpoints at equal coordinate (so ``[a,b)`` and
 ``[b,c)`` never match); ``jnp.lexsort`` with the hi/lo flag as secondary
@@ -185,10 +190,12 @@ def sbm_count_binary(S: Regions, U: Regions) -> int:
 #      roles, these are the s whose lo lies in (u.lo, u.hi): the
 #      contiguous range [bB_u, cB_u) of lo-sorted S.
 #
-# Both classes are searchsorted ranges, so pass 1 yields exact per-emitter
-# counts, an exclusive scan yields output offsets, and pass 2 emits every
-# pair into its slot fully in parallel — no data-dependent window, no
-# host-side l_max measurement, no overflow on long-region workloads.
+# Both ranges' ends are ranks (counts of lo endpoints below a value),
+# which pass 1 reads off one merged endpoint sort (``_merged_ranks``).  So
+# pass 1 yields exact per-emitter counts, an exclusive scan yields output
+# offsets, and pass 2 emits every pair into its slot fully in parallel —
+# no data-dependent window, no host-side l_max measurement, no overflow on
+# long-region workloads.
 # (The scan saturates at max_pairs so slot arithmetic stays in int32 even
 # when the true K exceeds the buffer; the exact K is summed host-side in
 # int64 from the unclipped per-emitter counts.)
@@ -210,8 +217,42 @@ def saturating_prefix(counts, lim: int):
     return jnp.where(sat, jnp.int32(lim), jnp.minimum(inc, lim))
 
 
+def _merged_ranks(s_lo, s_hi, u_lo, u_hi):
+    """Pass 1's four ranks from one stable merged endpoint sort.
+
+    Returns int32 ``(cB, rA, aA, bB)``: ``cB = #{s.lo < u.hi}`` and
+    ``bB = #{s.lo <= u.lo}`` per u, ``rA = #{u.lo < s.hi}`` and
+    ``aA = #{u.lo < s.lo}`` per s — the left/right ranks into the
+    lo-sorted arrays.  Sorting ``concat(u_hi, s_hi, s_lo, u_lo)`` stably
+    orders ties ``u_hi < s_hi < s_lo < u_lo``, which meets all four
+    ``<`` / ``<=`` conventions at once; the sort's comparator is the one
+    ``jnp.searchsorted`` uses, so -0.0 ties with 0.0 in both.  Each rank
+    is then a running count of u.lo entries (at an s endpoint) or s.lo
+    entries (at a u endpoint) in sorted order, and a second sort on the
+    position puts the ranks back in concatenation order.  Two sorts and
+    two cumsums: no gather, scatter or loop of dependent gathers, which
+    is what each scan-method ``searchsorted`` is.
+    """
+    n, m = s_lo.shape[0], u_lo.shape[0]
+    key = jnp.concatenate([u_hi, s_hi, s_lo, u_lo])
+    pos = jax.lax.iota(jnp.int32, key.shape[0])
+    _, pos = jax.lax.sort((key, pos), num_keys=1, is_stable=True)
+    is_s = (pos >= m) & (pos < m + 2 * n)
+    is_s_lo = is_s & (pos >= m + n)
+    is_u_lo = pos >= m + 2 * n
+    # inclusive counts: no s endpoint is a u.lo, no u endpoint an s.lo
+    rank = jnp.where(is_s, jnp.cumsum(is_u_lo, dtype=jnp.int32),
+                     jnp.cumsum(is_s_lo, dtype=jnp.int32))
+    _, rank = jax.lax.sort((pos, rank), num_keys=1)
+    return (rank[:m], rank[m:m + n], rank[m + n:m + 2 * n],
+            rank[m + 2 * n:])
+
+
 def _twopass_phase1(s_lo, s_hi, u_lo, u_hi, max_pairs: int):
     """Pass 1 of count-then-emit: per-emitter counts and slot offsets.
+
+    The lo-sort permutations are two stable argsorts; the range ends of
+    every emitter come from one merged endpoint sort (``_merged_ranks``).
 
     Returns ``(perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b)``:
     ``starts`` is the concatenated per-emitter input offsets (aA for the n
@@ -224,16 +265,10 @@ def _twopass_phase1(s_lo, s_hi, u_lo, u_hi, max_pairs: int):
     with jax.named_scope("ddm.pass1.sort"):
         perm_u = jnp.argsort(u_lo).astype(jnp.int32)
         perm_s = jnp.argsort(s_lo).astype(jnp.int32)
-        u_lo_sorted = u_lo[perm_u]
-        s_lo_sorted = s_lo[perm_s]
 
     # exact per-emitter counts (A: one emitter per s; B: per u)
     with jax.named_scope("ddm.pass1.search"):
-        aA = jnp.searchsorted(u_lo_sorted, s_lo, side="left").astype(jnp.int32)
-        rA = jnp.searchsorted(u_lo_sorted, s_hi, side="left").astype(jnp.int32)
-        bB = jnp.searchsorted(s_lo_sorted, u_lo,
-                              side="right").astype(jnp.int32)
-        cB = jnp.searchsorted(s_lo_sorted, u_hi, side="left").astype(jnp.int32)
+        cB, rA, aA, bB = _merged_ranks(s_lo, s_hi, u_lo, u_hi)
         # the maximum(·, 0) guards the offsets scan against degenerate
         # (empty, lo == hi) intervals, which violate the module
         # precondition but must not corrupt emission for the well-formed

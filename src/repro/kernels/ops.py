@@ -297,7 +297,7 @@ def twopass_pairs_pallas(S: Regions, U: Regions, max_pairs: int, *,
                          dense_only: bool = False):
     """Exact 1-D pair enumeration, pass 2 fused into one Pallas kernel.
 
-    Pass 1 (sort + searchsorted counts + saturated offset scan) stays on
+    Pass 1 (sorts + merged-sort rank counts + saturated offset scan) stays on
     XLA; the slot→(emitter, rank) lookup and the pair write run as a
     ``kernels.emit`` Mosaic kernel.  Same contract as
     ``core.sbm.sbm_pairs``: ``(pairs, exact count)``, truncation

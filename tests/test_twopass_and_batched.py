@@ -5,19 +5,24 @@ Two-pass enumeration (core.sbm / core.dd_match): exact pair sets and
 counts vs the numpy brute-force oracle for d ∈ {1, 2, 3}, including
 empty sets, duplicate endpoints (integer-grid regime), truncation
 reporting, and the long-region workloads whose data-dependent window
-made the old bounded-window path blow up.
+made the old bounded-window path blow up.  Pass 1's tables, bit for bit
+against a NumPy reference on stable argsorts and searchsorted.
 
 Batched service (core.dynamic): ``update_regions`` deltas and ledger
 must be identical to a sequence of single ``update_region`` calls on
 randomized workloads, including zero-churn and duplicate-index batches.
 """
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
-import jax.numpy as jnp
 
 from repro.core import (DDMService, Regions, make_regions, pairs_to_set,
                         paper_workload)
 from repro.core import brute, itm, sbm
+from repro.kernels import emit, ops
 
 from proputils import interval_cases, oracle_mask, plan_count, plan_pairs
 
@@ -103,6 +108,155 @@ def test_saturating_prefix_exact_past_int32(seed):
         got = np.asarray(sbm.saturating_prefix(jnp.asarray(counts), lim))
         want = np.minimum(np.cumsum(counts.astype(np.int64)), lim)
         np.testing.assert_array_equal(got, want)
+
+
+# Pass 1 against a NumPy reference built on stable argsorts and
+# searchsorted: every output bit for bit, on the inputs where tie order
+# decides the answer.  Each case is (n, m, max_pairs, s_lo, s_hi, u_lo,
+# u_hi) as 1-D float32 arrays.
+
+def _grid_case(rng, n, m, grid, max_pairs):
+    """lo and hi drawn from a small grid: ties within and across all four
+    endpoint classes."""
+    s_lo = rng.choice(grid, n)
+    u_lo = rng.choice(grid, m)
+    return (n, m, max_pairs, s_lo, s_lo + rng.choice(grid[1:] - grid[0], n),
+            u_lo, u_lo + rng.choice(grid[1:] - grid[0], m))
+
+
+def _pass1_case(name, seed=0):
+    rng = np.random.default_rng(seed)
+    f32 = np.float32
+    if name == "ties":
+        case = _grid_case(rng, 70, 90, np.arange(5, dtype=f32), 10**6)
+    elif name == "signed_zero":
+        z = np.array([-0.0, 0.0], f32)
+        s_lo, u_lo = rng.choice(z, 40), rng.choice(z, 30)
+        s_hi = np.where(rng.random(40) < 0.5, rng.choice(z, 40), f32(1))
+        u_hi = np.where(rng.random(30) < 0.5, rng.choice(z, 30), f32(1))
+        s_lo = np.where(rng.random(40) < 0.3, f32(-1), s_lo)
+        u_lo = np.where(rng.random(30) < 0.3, f32(-1), u_lo)
+        case = (40, 30, 10**6, s_lo, s_hi, u_lo, u_hi)
+    elif name == "duplicate_regions":
+        base_lo = rng.uniform(0, 10, 6).astype(f32)
+        base_hi = base_lo + rng.uniform(0.5, 4, 6).astype(f32)
+        i, j = rng.integers(0, 6, 50), rng.integers(0, 6, 45)
+        case = (50, 45, 10**6, base_lo[i], base_hi[i], base_lo[j],
+                base_hi[j])
+    elif name == "degenerate":
+        n, m, mp, s_lo, s_hi, u_lo, u_hi = _grid_case(
+            rng, 60, 50, np.arange(4, dtype=f32), 10**6)
+        s_hi = np.where(rng.random(n) < 0.3, s_lo, s_hi)
+        u_hi = np.where(rng.random(m) < 0.3, u_lo, u_hi)
+        case = (n, m, mp, s_lo, s_hi, u_lo, u_hi)
+    elif name == "infinite":
+        v = np.array([-np.inf, -1, 0, 1, np.inf], f32)
+        s_lo, s_hi = np.sort(rng.choice(v, (2, 40)), axis=0)
+        u_lo, u_hi = np.sort(rng.choice(v, (2, 35)), axis=0)
+        case = (40, 35, 10**6, s_lo, s_hi, u_lo, u_hi)
+    elif name == "n_ne_m":
+        case = _grid_case(rng, 13, 170, np.arange(6, dtype=f32), 10**6)
+    elif name == "n_is_1":
+        case = _grid_case(rng, 1, 64, np.arange(4, dtype=f32), 10**6)
+    elif name == "m_is_1":
+        case = _grid_case(rng, 64, 1, np.arange(4, dtype=f32), 10**6)
+    elif name == "saturating":
+        case = _grid_case(rng, 80, 80, np.arange(3, dtype=f32), 37)
+    n, m, mp, *arrs = case
+    return (n, m, mp, *(np.asarray(a, f32) for a in arrs))
+
+
+PASS1_CASES = ("ties", "signed_zero", "duplicate_regions", "degenerate",
+               "infinite", "n_ne_m", "n_is_1", "m_is_1", "saturating")
+
+
+def _pass1_reference(s_lo, s_hi, u_lo, u_hi, max_pairs):
+    """``_twopass_phase1``'s seven outputs, from NumPy searches."""
+    perm_s = np.argsort(s_lo, kind="stable").astype(np.int32)
+    perm_u = np.argsort(u_lo, kind="stable").astype(np.int32)
+    sl, ul = s_lo[perm_s], u_lo[perm_u]
+    aA = np.searchsorted(ul, s_lo, side="left")
+    rA = np.searchsorted(ul, s_hi, side="left")
+    bB = np.searchsorted(sl, u_lo, side="right")
+    cB = np.searchsorted(sl, u_hi, side="left")
+    cnt_a = np.maximum(rA - aA, 0).astype(np.int32)
+    cnt_b = np.maximum(cB - bB, 0).astype(np.int32)
+    counts = np.concatenate([cnt_a, cnt_b])
+    offs = np.concatenate(
+        [[0], np.minimum(np.cumsum(counts, dtype=np.int64), max_pairs)])
+    return (perm_s, perm_u, np.concatenate([aA, bB]).astype(np.int32),
+            counts, offs.astype(np.int32), cnt_a, cnt_b)
+
+
+@pytest.mark.parametrize("name", PASS1_CASES)
+def test_pass1_ranks_equal_searchsorted_reference(name):
+    """Pass 1's merged-sort ranks are bit-identical to the four
+    searchsorted ranks into the stable lo-sorts they replace."""
+    n, m, max_pairs, *arrs = _pass1_case(name)
+    got = jax.jit(sbm._twopass_phase1, static_argnames=("max_pairs",))(
+        *map(jnp.asarray, arrs), max_pairs=max_pairs)
+    want = _pass1_reference(*arrs, max_pairs)
+    for label, g, w in zip(("perm_s", "perm_u", "starts", "counts",
+                            "offs", "cnt_a", "cnt_b"), got, want):
+        assert g.dtype == jnp.int32, label
+        np.testing.assert_array_equal(np.asarray(g), w, err_msg=label)
+
+
+def _reference_pairs(n, max_pairs, perm_s, perm_u, starts, counts, offs):
+    """Pass 2 in NumPy: emitter e's j-th pair lands in slot offs[e] + j."""
+    out = np.full((max_pairs, 2), -1, np.int32)
+    for e, (o, c, st) in enumerate(zip(offs[:-1], counts, starts)):
+        for j in range(min(int(c), max_pairs - int(o))):
+            out[o + j] = ((e, perm_u[st + j]) if e < n
+                          else (perm_s[st + j], e - n))
+    return out
+
+
+@pytest.mark.parametrize("program,name", [
+    ("twopass_tables", "ties"), ("csr_tables", "signed_zero"),
+    ("twopass_emit", "saturating")])
+def test_pass1_programs_equal_searchsorted_reference(program, name):
+    """The three programs pass 1 feeds see the reference's tables."""
+    n, m, max_pairs, *arrs = _pass1_case(name)
+    args = tuple(map(jnp.asarray, arrs))
+    perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b = _pass1_reference(
+        *arrs, max_pairs)
+    if program == "twopass_tables":
+        got = ops._twopass_tables(*args, max_pairs=max_pairs)
+        want = (perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b)
+    elif program == "csr_tables":
+        block = 256
+        got = ops._csr_tables(*args, max_pairs=max_pairs, block=block)
+        tab = emit.pack_emitter_tables(
+            jnp.asarray(offs), jnp.asarray(counts), jnp.asarray(starts),
+            n=n, m=m, min_len=emit.stream_window(block))
+        want = (tab, emit.pad_perm(jnp.asarray(perm_s)),
+                emit.pad_perm(jnp.asarray(perm_u)), cnt_a, cnt_b)
+    else:
+        got = sbm._twopass_emit(*args, max_pairs=max_pairs)
+        want = (_reference_pairs(n, max_pairs, perm_s, perm_u, starts,
+                                 counts, offs), cnt_a, cnt_b)
+        assert int(counts.sum()) > max_pairs
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"{program} output {i}")
+
+
+@pytest.mark.parametrize("program", ("twopass_tables", "csr_tables"))
+def test_flat_pass1_programs_compile_without_a_loop(program):
+    """Pass 1 ranks by sorting: the compiled flat table programs hold no
+    ``while`` (a scan-method searchsorted is one)."""
+    f32 = jax.ShapeDtypeStruct((96,), jnp.float32)
+    if program == "twopass_tables":
+        lowered = ops._twopass_tables.lower(f32, f32, f32, f32,
+                                            max_pairs=512)
+    else:
+        lowered = ops._csr_tables.lower(f32, f32, f32, f32, max_pairs=512,
+                                        block=256)
+    hlo = lowered.compile().as_text()
+    assert re.search(r"\ssort\(", hlo)
+    assert not re.search(r"\swhile\(", hlo)
 
 
 def test_count_dd_no_overflow_with_small_max_pairs():
