@@ -27,8 +27,9 @@ non-zero count, so compacted offsets are strictly increasing below the
 saturation limit and each emitter ``k`` owns the contiguous slot run
 ``[offs[k], offs[k] + counts[k])``.  One output tile of ``B`` slots is
 therefore covered by at most ``B + 1`` consecutive emitters, starting at
-the owner of the tile's first slot.  The XLA side finds that first and
-last owner per tile with one vectorized searchsorted each and passes
+the owner of the tile's first slot.  The XLA side compacts the table
+with one sort, finds the first and last owner of every tile with one
+vectorized searchsorted over all of them (``tile_meta``) and passes
 them, with the tile's 128-aligned table window base, as scalar-prefetch
 operands.
 
@@ -176,27 +177,26 @@ def pack_emitter_tables(offs, counts, starts, *, n: int, m: int,
     E_pad at the widest window a consumer will slice; pad entries
     carry offset ``_PAD_OFF``, count 0 and emitter id n + m, so they
     never write a slot.
+
+    The compaction is one sort: each emitter's key is its index, plus
+    ``n + m`` when it is dropped, so survivors come first in emitter
+    order and carry their rows along: no scatter and no gather.
     """
     E = n + m
-    sel = jnp.nonzero(counts > 0, size=E, fill_value=E)[0].astype(jnp.int32)
-    ok = sel < E
-    selc = jnp.minimum(sel, E - 1)
-    c_offs = jnp.where(ok, offs[selc], _PAD_OFF)
-    c_counts = jnp.where(ok, counts[selc], 0)
-    c_starts = jnp.where(ok, starts[selc], 0)
-    c_eorig = jnp.where(ok, sel, E)
-
-    pad = table_len(E, min_len) - E
-    if pad > 0:
-        c_offs = jnp.pad(c_offs, (0, pad), constant_values=_PAD_OFF)
-        c_counts = jnp.pad(c_counts, (0, pad))
-        c_starts = jnp.pad(c_starts, (0, pad))
-        c_eorig = jnp.pad(c_eorig, (0, pad), constant_values=E)
-    e_pad = c_offs.shape[0]
-    tab = jnp.zeros((8, e_pad), jnp.int32)
-    tab = tab.at[0].set(c_offs).at[1].set(c_counts)
-    tab = tab.at[2].set(c_starts).at[3].set(c_eorig)
-    return tab
+    with jax.named_scope("ddm.emit.pack"), \
+            jax.named_scope("ddm.emit.pack.compact"):
+        idx = jax.lax.iota(jnp.int32, E)
+        key = jnp.where(counts > 0, idx, idx + E)
+        # keys are unique, so the sort needs no stability
+        cols = jnp.stack(jax.lax.sort((key, offs[:E], counts, starts),
+                                      num_keys=1, is_stable=False))
+        # lane padding: a key of E marks a pad entry like a dropped one
+        cols = jnp.pad(cols, ((0, 0), (0, table_len(E, min_len) - E)),
+                       constant_values=E)
+        ok = cols[0] < E
+        rows = [jnp.where(ok, cols[1], _PAD_OFF), jnp.where(ok, cols[2], 0),
+                jnp.where(ok, cols[3], 0), jnp.where(ok, cols[0], E)]
+        return jnp.pad(jnp.stack(rows), ((0, 4), (0, 0)))
 
 
 def pad_perm(perm):
@@ -215,20 +215,23 @@ def tile_meta(c_offs, w0, *, nt: int, block: int, win: int,
     ``limit`` (the saturation limit, when known), and ``base`` is the
     128-aligned start of the table window that holds them.  ``k_last``
     is clipped to that window, which only matters for slots at or past
-    the limit — those are trimmed by every caller.
+    the limit — those are trimmed by every caller.  The first and last
+    slots of all ``nt`` tiles share one search.
     """
     e_pad = c_offs.shape[0]
-    t0 = w0 + jnp.arange(nt, dtype=jnp.int32) * block
-    t_end = t0 + (block - 1)
-    if limit is not None:
-        t_end = jnp.minimum(t_end, limit - 1)
-    k_first = jnp.maximum(
-        jnp.searchsorted(c_offs, t0, side="right").astype(jnp.int32) - 1, 0)
-    base = jnp.minimum((k_first // 128) * 128, e_pad - win)
-    k_last = jnp.minimum(
-        jnp.searchsorted(c_offs, t_end, side="right").astype(jnp.int32) - 1,
-        base + win - 1)
-    return jnp.concatenate([jnp.reshape(w0, (1,)), base, k_first, k_last])
+    with jax.named_scope("ddm.emit.pack"), \
+            jax.named_scope("ddm.emit.pack.meta"):
+        t0 = w0 + jnp.arange(nt, dtype=jnp.int32) * block
+        t_end = t0 + (block - 1)
+        if limit is not None:
+            t_end = jnp.minimum(t_end, limit - 1)
+        owner = jnp.searchsorted(c_offs, jnp.concatenate([t0, t_end]),
+                                 side="right").astype(jnp.int32) - 1
+        k_first = jnp.maximum(owner[:nt], 0)
+        base = jnp.minimum((k_first // 128) * 128, e_pad - win)
+        k_last = jnp.minimum(owner[nt:], base + win - 1)
+        return jnp.concatenate([jnp.reshape(w0, (1,)), base, k_first,
+                                k_last])
 
 
 # ---------------------------------------------------------------------------
@@ -394,13 +397,14 @@ def _emit_slots(tab, perm_s_pad, perm_u_pad, w0, *, n: int, nslots: int,
     of at most ``MAX_TILES`` tiles of ``block`` slots each."""
     win = stream_window(block)
     nt = -(-nslots // block)
+    meta = tile_meta(tab[0], w0, nt=nt, block=block, win=win, limit=limit)
+    rows = meta[1:].reshape(3, nt)
     halves = []
     for c0 in range(0, nt, MAX_TILES):
         nc = min(MAX_TILES, nt - c0)
-        with jax.named_scope("ddm.emit.pack"):
-            meta = tile_meta(tab[0], w0 + c0 * block, nt=nc, block=block,
-                             win=win, limit=limit)
-        halves.append(emit_call(meta, tab, perm_s_pad, perm_u_pad, n=n,
+        chunk = jnp.concatenate([meta[:1] + c0 * block,
+                                 rows[:, c0:c0 + nc].reshape(-1)])
+        halves.append(emit_call(chunk, tab, perm_s_pad, perm_u_pad, n=n,
                                 nt=nc, block=block, mode=mode,
                                 interpret=interpret))
     s_out = jnp.concatenate([h[0][0] for h in halves])[:nslots]
@@ -413,9 +417,8 @@ def _dense_emit(offs, counts, starts, perm_s, perm_u, *, n: int, m: int,
     if max_pairs == 0:
         return _empty_pairs()
     bl = min(lane_pad(block), max(128, lane_pad(max_pairs)))
-    with jax.named_scope("ddm.emit.pack"):
-        tab = pack_emitter_tables(offs, counts, starts, n=n, m=m,
-                                  min_len=stream_window(bl))
+    tab = pack_emitter_tables(offs, counts, starts, n=n, m=m,
+                              min_len=stream_window(bl))
     return _emit_slots(tab, pad_perm(perm_s), pad_perm(perm_u),
                        jnp.int32(0), n=n, nslots=max_pairs, block=bl,
                        mode=mode, interpret=interpret, limit=max_pairs)

@@ -260,10 +260,9 @@ def _csr_tables(s_lo, s_hi, u_lo, u_hi, max_pairs, block):
     n, m = s_lo.shape[0], u_lo.shape[0]
     perm_s, perm_u, starts, counts, offs, cnt_a, cnt_b = _twopass_phase1(
         s_lo, s_hi, u_lo, u_hi, max_pairs)
-    with jax.named_scope("ddm.emit.pack"):
-        tab = emit_kernel.pack_emitter_tables(
-            offs, counts, starts, n=n, m=m,
-            min_len=emit_kernel.stream_window(block))
+    tab = emit_kernel.pack_emitter_tables(
+        offs, counts, starts, n=n, m=m,
+        min_len=emit_kernel.stream_window(block))
     return (tab, emit_kernel.pad_perm(perm_s), emit_kernel.pad_perm(perm_u),
             cnt_a, cnt_b)
 
@@ -376,10 +375,9 @@ def _hsbm_csr_tables(s_lo, s_hi, u_lo, u_hi, lb, width, *, ncells, cap_s,
         suf_s=suf_s, cap_u=cap_u, suf_u=suf_u, max_pairs=max_pairs)
     n_a = ncells * (cap_s + suf_s)
     n_b = ncells * (cap_u + suf_u)
-    with jax.named_scope("ddm.emit.pack"):
-        tab = emit_kernel.pack_emitter_tables(
-            offs, counts, starts, n=n_a, m=n_b,
-            min_len=emit_kernel.stream_window(block))
+    tab = emit_kernel.pack_emitter_tables(
+        offs, counts, starts, n=n_a, m=n_b,
+        min_len=emit_kernel.stream_window(block))
     return (tab, emit_kernel.pad_perm(sid + n_a),
             emit_kernel.pad_perm(uid + n_b), sid, uid, counts)
 

@@ -14,6 +14,8 @@ ordinary XLA.
 The topology is described inside a fixture, so no worker touches the
 TPU library while collecting tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -95,6 +97,30 @@ def test_csr_decode_compiles(one_chip):
     _compile(call, _i32(1 + 3 * nt), _i32(8, emit.table_len(e, win)),
              _i32(1, emit.perm_len(n)), _i32(1, emit.perm_len(m)),
              sharding=one_chip)
+
+
+def test_emit_packing_compiles_to_sorts(one_chip):
+    """The a100 batch cell's emit program: the table compaction (scope
+    ``ddm.emit.pack.compact``) compiles to a sort, with no scatter,
+    gather or loop; the tile owners (``ddm.emit.pack.meta``) take one
+    search loop for all 32,768 tiles, not one per kernel call."""
+    n = m = 500_000
+    e = n + m
+    lowered = emit.twopass_emit_streaming.lower(
+        *(jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+          for s in ((e + 1,), (e,), (e,), (n,), (m,))),
+        n=n, m=m, max_pairs=1 << 26, block=BLOCK)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    pack = [ln for ln in text.splitlines() if "ddm.emit.pack" in ln]
+    compact = [ln for ln in pack if "ddm.emit.pack.compact" in ln]
+    assert any(re.search(r"\ssort\(", ln) for ln in compact)
+    bad = [ln.split(" = ")[0].strip() for ln in compact
+           if re.search(r"\s(scatter|gather|while)\(", ln)]
+    assert not bad, bad
+    assert not [ln for ln in pack if re.search(r"\sscatter\(", ln)]
+    loops = [ln for ln in pack if re.search(r"\swhile\(", ln)]
+    assert len(loops) == 1, loops
 
 
 def test_sbm_sweep_compiles(one_chip):

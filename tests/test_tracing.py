@@ -120,9 +120,10 @@ def _lower_exchange():
 
 @pytest.mark.parametrize("lower,scopes", [
     (_lower_twopass_tables, PASS1),
-    (_lower_csr_tables, PASS1 | {"ddm.emit.pack"}),
+    (_lower_csr_tables, PASS1 | {"ddm.emit.pack", "ddm.emit.pack.compact"}),
     (_lower_hsbm_tables, PASS1),
-    (_lower_emit_streaming, {"ddm.emit.pack"}),
+    (_lower_emit_streaming, {"ddm.emit.pack", "ddm.emit.pack.compact",
+                             "ddm.emit.pack.meta"}),
     (_lower_verify, {"ddm.verify"}),
     (_lower_exchange, {"ddm.exchange"}),
 ], ids=["twopass_tables", "csr_tables", "hsbm_tables",

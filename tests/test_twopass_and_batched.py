@@ -12,6 +12,7 @@ Batched service (core.dynamic): ``update_regions`` deltas and ledger
 must be identical to a sequence of single ``update_region`` calls on
 randomized workloads, including zero-churn and duplicate-index batches.
 """
+import functools
 import re
 
 import jax
@@ -257,6 +258,143 @@ def test_flat_pass1_programs_compile_without_a_loop(program):
     hlo = lowered.compile().as_text()
     assert re.search(r"\ssort\(", hlo)
     assert not re.search(r"\swhile\(", hlo)
+
+
+@pytest.mark.parametrize("program", ("csr_tables", "twopass_emit_streaming"))
+def test_emit_packing_compiles_without_a_scatter(program):
+    """Table packing compacts by sorting: no ``jnp.nonzero`` scatter."""
+    f32 = jax.ShapeDtypeStruct((96,), jnp.float32)
+    i32 = lambda k: jax.ShapeDtypeStruct((k,), jnp.int32)  # noqa: E731
+    if program == "csr_tables":
+        lowered = ops._csr_tables.lower(f32, f32, f32, f32, max_pairs=512,
+                                        block=256)
+    else:
+        lowered = emit.twopass_emit_streaming.lower(
+            i32(193), i32(192), i32(192), i32(96), i32(96), n=96, m=96,
+            max_pairs=512, block=256, interpret=True)
+    hlo = lowered.compile().as_text()
+    assert not re.search(r"\sscatter\(", hlo)
+
+
+# The emit layer's table packing against the gather-based code it
+# replaced: the compacted table and the tile metadata, bit for bit.
+
+def _pack_reference(offs, counts, starts, *, n, m, min_len):
+    """``jnp.nonzero`` compaction, then a gather per row."""
+    E = n + m
+    sel = jnp.nonzero(counts > 0, size=E, fill_value=E)[0].astype(jnp.int32)
+    ok = sel < E
+    selc = jnp.minimum(sel, E - 1)
+    rows = [jnp.where(ok, offs[selc], emit._PAD_OFF),
+            jnp.where(ok, counts[selc], 0), jnp.where(ok, starts[selc], 0),
+            jnp.where(ok, sel, E)]
+    pad = emit.table_len(E, min_len) - E
+    rows = [jnp.pad(r, (0, pad), constant_values=c)
+            for r, c in zip(rows, (emit._PAD_OFF, 0, 0, E))]
+    return jnp.pad(jnp.stack(rows), ((0, 4), (0, 0)))
+
+
+def _emitter_tables(rng, E, pattern, max_pairs):
+    """``(offs, counts, starts)`` as pass 1 hands them to the packer."""
+    counts = rng.integers(1, 40, E)
+    if pattern == "all_zero":
+        counts[:] = 0
+    elif pattern == "sparse":
+        counts *= rng.random(E) < 0.01
+    elif pattern != "all_nonzero":
+        counts *= rng.random(E) < 0.6
+    counts = counts.astype(np.int32)
+    offs = np.concatenate([[0], np.minimum(np.cumsum(counts), max_pairs)])
+    starts = rng.integers(0, 1000, E).astype(np.int32)
+    return offs.astype(np.int32), counts, starts
+
+
+# (n, m, count pattern, max_pairs, min_len)
+PACK_CASES = {
+    "all_zero": (300, 212, "all_zero", 10**6, 512),
+    "all_nonzero": (256, 256, "all_nonzero", 10**6, 512),
+    "one_percent": (2000, 3000, "sparse", 10**6, 512),
+    "saturated": (320, 320, "mixed", 500, 512),
+    "ragged": (487, 513, "mixed", 10**6, 384),
+    "min_len_past_e": (90, 110, "mixed", 10**6, 2304),
+}
+
+
+@pytest.mark.parametrize("name", PACK_CASES)
+def test_pack_emitter_tables_equals_nonzero_reference(name):
+    n, m, pattern, max_pairs, min_len = PACK_CASES[name]
+    offs, counts, starts = map(jnp.asarray, _emitter_tables(
+        np.random.default_rng(len(name)), n + m, pattern, max_pairs))
+    got = emit.pack_emitter_tables(offs, counts, starts, n=n, m=m,
+                                   min_len=min_len)
+    want = _pack_reference(offs, counts, starts, n=n, m=m, min_len=min_len)
+    if name == "saturated":       # several emitters share offset max_pairs
+        assert int(jnp.sum((want[0] == max_pairs) & (want[1] > 0))) > 10
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _meta_reference(c_offs, w0, *, nt, block, win, limit):
+    """``tile_meta`` from NumPy searchsorted."""
+    c_offs = np.asarray(c_offs)
+    t0 = w0 + np.arange(nt) * block
+    t_end = t0 + block - 1
+    if limit is not None:
+        t_end = np.minimum(t_end, limit - 1)
+    k_first = np.maximum(np.searchsorted(c_offs, t0, side="right") - 1, 0)
+    base = np.minimum(k_first // 128 * 128, c_offs.size - win)
+    k_last = np.minimum(np.searchsorted(c_offs, t_end, side="right") - 1,
+                        base + win - 1)
+    return np.concatenate([[w0], base, k_first, k_last]).astype(np.int32)
+
+
+# shape -> (emitters, tiles, block)
+META_SHAPES = {"few_tiles_wide_table": (20_000, 4, 256),
+               "many_tiles": (1_500, 400, 128)}
+
+
+@pytest.mark.parametrize("limit", (None, "set"))
+@pytest.mark.parametrize("shape", META_SHAPES)
+def test_tile_meta_equals_searchsorted_reference(shape, limit):
+    E, nt, block = META_SHAPES[shape]
+    win = emit.stream_window(block)
+    max_pairs = 12 * E                 # about half the emitters saturate
+    offs, counts, starts = _emitter_tables(np.random.default_rng(nt), E,
+                                           "mixed", max_pairs)
+    c_offs = emit.pack_emitter_tables(
+        *map(jnp.asarray, (offs, counts, starts)), n=E // 2, m=E - E // 2,
+        min_len=win)[0]
+    w0 = max_pairs - nt * block // 2    # tiles on both sides of the limit
+    lim = max_pairs if limit else None
+    fn = functools.partial(emit.tile_meta, nt=nt, block=block, win=win,
+                           limit=lim)
+    # the first and last slots of every tile share one search
+    jaxpr = str(jax.make_jaxpr(fn)(c_offs, jnp.int32(w0)))
+    assert jaxpr.count("searchsorted") == 1
+    np.testing.assert_array_equal(
+        np.asarray(fn(c_offs, jnp.int32(w0))),
+        _meta_reference(c_offs, w0, nt=nt, block=block, win=win,
+                        limit=lim))
+
+
+@pytest.mark.parametrize("nslots", (256, 4096))
+def test_csr_decode_window_mid_buffer_equals_xla_pass2(nslots):
+    """A window from the middle of the buffer, over two tiles and over
+    32, decodes to the XLA pass 2's slots."""
+    rng = np.random.default_rng(nslots)
+    n, m, max_pairs, block = 150, 170, 16_384, 128
+    lo = rng.uniform(0, 100, n + m).astype(np.float32)
+    hi = lo + rng.uniform(1, 40, n + m).astype(np.float32)
+    args = tuple(map(jnp.asarray, (lo[:n], hi[:n], lo[n:], hi[n:])))
+    tab, ps, pu, cnt_a, cnt_b = ops._csr_tables(*args, max_pairs=max_pairs,
+                                                block=block)
+    want = np.asarray(sbm._twopass_emit(*args, max_pairs=max_pairs)[0])
+    k = int(jnp.sum(cnt_a) + jnp.sum(cnt_b))
+    w0 = k // 2 - nslots // 2 + 37
+    assert 0 < w0 and w0 + nslots < k < max_pairs
+    got = emit.csr_decode_window(tab, ps, pu, jnp.int32(w0), n=n, m=m,
+                                 nslots=nslots, block=block, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want[w0:w0 + nslots])
 
 
 def test_count_dd_no_overflow_with_small_max_pairs():
